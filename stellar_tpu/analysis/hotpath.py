@@ -110,7 +110,8 @@ ALLOWLIST = Allowlist({
             "dict hit, no fresh wrapper and no retrace.",
         "jit-in-func:probe.jax.jit":
             "intentional: each breaker-paced probe must prove the "
-            "FULL tunnel including compile+dispatch (a cached wrapper "
+            "FULL device path including compile+dispatch (a cached "
+            "wrapper "
             "could vacuously re-close a dispatch-opened breaker); "
             "probes are exponential-backoff-paced, so the recompile "
             "cost is bounded by design.",

@@ -338,7 +338,7 @@ class IntervalInterpreter:
 
     def eval_closed(self, closed_jaxpr, invals: Sequence[AbsVal],
                     path: str = "jaxpr") -> List[AbsVal]:
-        import jax.core as core
+        from jax.extend import core
         jaxpr = closed_jaxpr.jaxpr if isinstance(
             closed_jaxpr, core.ClosedJaxpr) else closed_jaxpr
         consts = closed_jaxpr.consts if isinstance(
@@ -348,7 +348,8 @@ class IntervalInterpreter:
     # ---------------- core loop ----------------
 
     def _eval(self, jaxpr, consts, invals, path) -> List[AbsVal]:
-        import jax.core as core
+        from jax.core import DropVar
+        from jax.extend import core
         env: Dict = {}
         for var, c in zip(jaxpr.constvars, consts):
             env[var] = AbsVal.from_concrete(np.asarray(c))
@@ -368,7 +369,7 @@ class IntervalInterpreter:
             ins = [read(v) for v in eqn.invars]
             outs = self.run_eqn(eqn, ins, path, idx)
             for var, out in zip(eqn.outvars, outs):
-                if not isinstance(var, core.DropVar):
+                if not isinstance(var, DropVar):
                     env[var] = out
         return [read(v) for v in jaxpr.outvars]
 
@@ -419,6 +420,7 @@ class IntervalInterpreter:
             "reshape": self._h_reshape, "squeeze": self._h_squeeze,
             "transpose": self._h_transpose, "rev": self._h_rev,
             "concatenate": self._h_concatenate, "pad": self._h_pad,
+            "tile": self._h_tile,
             "slice": self._h_slice, "dynamic_slice": self._h_dynamic_slice,
             "iota": self._h_iota,
             "reduce_sum": self._h_reduce_sum,
@@ -430,7 +432,8 @@ class IntervalInterpreter:
             "dot_general": self._h_dot_general,
             "device_put": self._h_identity, "copy": self._h_identity,
             "stop_gradient": self._h_identity,
-            "pjit": self._h_pjit, "closed_call": self._h_pjit,
+            "pjit": self._h_pjit, "jit": self._h_pjit,
+            "closed_call": self._h_pjit,
             "scan": self._h_scan,
         }
         return h
@@ -854,6 +857,16 @@ class IntervalInterpreter:
         hi = np.concatenate(his, axis=dim)
         return AbsVal(lo, hi, aval.shape, aval.dtype).collapsed()
 
+    def _h_tile(self, eqn, ins, path, idx):
+        # jnp.tile's primitive (JAX >= 0.9): values repeat, so bounds
+        # repeat; a collapsed (size-1) axis stays collapsed
+        (a,) = ins
+        aval = eqn.outvars[0].aval
+        reps = [r if a.lo.shape[d] > 1 else 1
+                for d, r in enumerate(eqn.params["reps"])]
+        return AbsVal(np.tile(a.lo, reps), np.tile(a.hi, reps),
+                      aval.shape, aval.dtype).collapsed()
+
     def _h_pad(self, eqn, ins, path, idx):
         a, padval = ins
         cfg = eqn.params["padding_config"]
@@ -1250,8 +1263,10 @@ class _IncrementalBody:
 
     def __init__(self, interp: IntervalInterpreter, closed_jaxpr,
                  path: str):
-        import jax.core as core
+        from jax.core import DropVar
+        from jax.extend import core
         self._core = core
+        self._drop_var = DropVar
         self._interp = interp
         self._jaxpr = closed_jaxpr.jaxpr
         self._path = path
@@ -1291,7 +1306,7 @@ class _IncrementalBody:
                 self._prev_in[idx] = in_ids
                 self._prev_out[idx] = outs
             for var, out in zip(eqn.outvars, outs):
-                if not isinstance(var, core.DropVar):
+                if not isinstance(var, self._drop_var):
                     env[var] = out
         out = []
         for v in self._jaxpr.outvars:

@@ -91,35 +91,35 @@ ALLOWLIST = Allowlist({
             "here is the pool's steady state, not a hang.",
     },
     "stellar_tpu/utils/native.py": {
-        "hold-and-block:_load.subprocess":
+        "hold-and-block:_load.build_lib.subprocess":
             "one-shot compile-and-dlopen serialization: the lock "
-            "exists precisely so exactly one thread runs g++ while "
-            "late arrivals wait for the cached library; the compile "
-            "is bounded (subprocess timeout=120) and happens once "
-            "per process, before the threaded dispatch tier exists.",
+            "exists precisely so exactly one thread runs g++ (through "
+            "the shared native_build.build_lib) while late arrivals "
+            "wait for the cached library; the compile is bounded "
+            "(subprocess timeout=120) and happens once per process, "
+            "before the threaded dispatch tier exists.",
     },
     "stellar_tpu/crypto/native_prep.py": {
-        "hold-and-block:_load.subprocess":
+        "hold-and-block:_load.build_lib.subprocess":
             "same one-shot compile serialization as utils/native.py: "
             "the module lock makes the g++ build (timeout-bounded) "
             "happen exactly once; every later call is a cached-lib "
             "return that never blocks.",
     },
     "stellar_tpu/crypto/native_verify.py": {
-        "hold-and-block:_load._build_lib.subprocess":
-            "same one-shot compile serialization as utils/native.py, "
-            "through the shared _build_lib helper: the module lock "
-            "makes the g++ build (timeout-bounded) happen exactly "
-            "once; every later call is a cached-lib return that "
-            "never blocks.",
+        "hold-and-block:_load.build_lib.subprocess":
+            "same one-shot compile serialization as utils/native.py: "
+            "the module lock makes the g++ build (timeout-bounded) "
+            "happen exactly once; every later call is a cached-lib "
+            "return that never blocks.",
     },
     "stellar_tpu/soroban/native_wasm.py": {
-        "hold-and-block:_load._build_lib.subprocess":
+        "hold-and-block:_load.build_lib.subprocess":
             "one-shot compile serialization (atomic publish protects "
             "concurrent PROCESSES; the lock serializes threads): the "
-            "timeout-bounded g++ build in _build_lib runs once per "
+            "timeout-bounded g++ build in build_lib runs once per "
             "process.",
-        "hold-and-block:_load_ext._build_lib.subprocess":
+        "hold-and-block:_load_ext.build_lib.subprocess":
             "same one-shot compile serialization as _load, for the "
             "CPython extension variant: timeout-bounded, once per "
             "process, before any dispatch-tier thread can contend.",

@@ -123,7 +123,7 @@ def verdict_record(device: Optional[int], lo: int, hi: int,
                    sampled: int, ok: bool) -> dict:
     """The evidence shape of one audit verdict, shared by the flight
     recorder's ``verify.audit.verdict`` events and the fault-domain
-    payload of ``MULTICHIP_r*`` captures (``tools/multichip_bench.py``)
+    payload of multi-chip captures (``tools/multichip_bench.py``)
     — one definition so both streams stay comparable. Pure data: no
     clocks, no RNG (this module is in the nondet-lint scope; consumers
     that need timestamps stamp their own)."""

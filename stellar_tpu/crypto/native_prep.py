@@ -11,18 +11,18 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
-import subprocess
 import threading
 from typing import Sequence
 
 import numpy as np
+
+from stellar_tpu.utils.native_build import build_lib
 
 __all__ = ["available", "prep_batch", "sha512_batch"]
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "ed25519_prep.cpp")
-_LIB = os.path.join(_REPO_ROOT, "build", "libed25519prep.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -38,14 +38,9 @@ def _load():
             return _lib
         _tried = True
         try:
-            if not os.path.exists(_LIB) or \
-                    os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-                os.makedirs(os.path.dirname(_LIB), exist_ok=True)
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-pthread",
-                     "-o", _LIB, _SRC],
-                    check=True, capture_output=True, timeout=120)
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(build_lib([_SRC], "libed25519prep",
+                                        extra_flags=["-pthread"],
+                                        timeout=120))
             u8p = ctypes.POINTER(ctypes.c_uint8)
             u64p = ctypes.POINTER(ctypes.c_uint64)
             lib.ed25519_prep_batch.argtypes = [

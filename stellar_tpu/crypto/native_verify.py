@@ -19,12 +19,13 @@ from typing import Sequence
 
 import numpy as np
 
+from stellar_tpu.utils.native_build import build_lib
+
 __all__ = ["available", "verify_eq_batch"]
 
 _HERE = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_HERE, "native", "ed25519_batch_verify.cpp")
-_LIB = os.path.join(_HERE, "build", "libed25519verify.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -41,9 +42,8 @@ def _load():
             return _lib
         _tried = True
         try:
-            from stellar_tpu.soroban.native_wasm import _build_lib
-            _build_lib([_SRC], _LIB, extra_flags=["-ldl"], timeout=120)
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(build_lib([_SRC], "libed25519verify",
+                                        extra_flags=["-ldl"], timeout=120))
             lib.ed25519_verify_available.restype = ctypes.c_int
             lib.ed25519_verify_batch.argtypes = [
                 _u8p, _u8p, _u8p, _u64p, _u64p, ctypes.c_uint64,

@@ -622,4 +622,6 @@ def main(argv=None) -> int:
     from stellar_tpu.main.cli_offline import register as register_offline
     register_offline(sub)
     args = p.parse_args(argv)
+    from stellar_tpu.utils.jax_setup import enable_compile_cache
+    enable_compile_cache()
     return args.fn(args)

@@ -222,14 +222,13 @@ def verify_kernel_sharded(mesh, axis_name="batch"):
     mesh-layout experiments.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         verify_kernel,
         mesh=mesh,
         in_specs=(P(axis_name, None), P(axis_name, None),
                   P(axis_name, None), P(axis_name, None)),
         out_specs=P(axis_name),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)

@@ -24,8 +24,7 @@ caught returning wrong bits must not get ``threshold - 1`` more
 chances to decide signature validity.
 
 Every state transition is recorded in a bounded in-memory history ring
-(``seq``-ordered; consumers such as ``tools/device_watch.py`` stamp
-wall-clock time themselves) and mirrored into per-device metrics
+(``seq``-ordered; consumers stamp wall-clock time themselves) and mirrored into per-device metrics
 gauges (``crypto.verify.device.<idx>.breaker.state``).
 
 Determinism: this module never reads clocks or RNGs itself (it is in
@@ -78,7 +77,7 @@ class DeviceHealth:
         self._seq = 0
         # per-device audit-verdict tallies (key "-1" = unattributable
         # single-device dispatch) — the fault-domain evidence
-        # MULTICHIP_r* capture runs carry (docs/observability.md)
+        # multi-chip capture runs carry (docs/observability.md)
         self._audits: Dict[str, Dict[str, int]] = {}
         self._threshold = int(failure_threshold
                               if failure_threshold is not None
@@ -138,7 +137,7 @@ class DeviceHealth:
         """Account one failure to device ``idx``. Returns True when
         THIS failure opened the device's breaker (quarantine onset) —
         the caller escalates correlated failures to the global breaker
-        so a whole-tunnel death doesn't pay n_devices independent
+        so a whole-host device outage doesn't pay n_devices independent
         quarantines of serialized deadline waits."""
         _metrics.counter(f"crypto.verify.device.{idx}.failures").inc()
         # the breaker reports the OPEN transition atomically (under its
@@ -162,7 +161,7 @@ class DeviceHealth:
         """Record one result-integrity audit verdict against device
         ``idx`` (None = unattributable single-device dispatch):
         per-device ok/mismatch tallies in the snapshot, a history
-        event on a mismatch, and counters — so a ``MULTICHIP_r*``
+        event on a mismatch, and counters — so a multi-chip
         capture carries the audit evidence alongside breaker states.
         Clock/RNG-free (this module is in the nondet-lint scope)."""
         key = "-1" if idx is None else str(int(idx))

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
+import sys
 import threading
 from typing import Dict, Optional, Tuple
 
@@ -23,22 +23,14 @@ from stellar_tpu.soroban.wasm import (
     HOST_CALL_COST, MAX_PAGES, Trap, WasmModule,
 )
 
+from stellar_tpu.utils.native_build import build_lib
+
 __all__ = ["available", "run_export"]
 
 _HERE = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_HERE, "native", "wasm_exec.cpp")
-_LIB = os.path.join(_HERE, "build", "libwasmexec.so")
 _EXT_SRC = os.path.join(_HERE, "native", "wasm_ext.cpp")
-
-
-def _ext_lib_path() -> str:
-    # ABI-tagged: the extension links against a specific CPython's
-    # internals (unlike libwasmexec.so, which is Python-free), so a
-    # stale .so from another interpreter version must never be loaded
-    import sys
-    return os.path.join(_HERE, "build",
-                        f"wasm_ext.{sys.implementation.cache_tag}.so")
 
 
 _lock = threading.Lock()
@@ -47,22 +39,6 @@ _tried = False
 _ext = None
 _ext_tried = False
 
-
-def _build_lib(srcs, out_path: str, extra_flags=(), timeout: int = 180):
-    """Compile-if-stale with an atomic publish: concurrent processes
-    must never dlopen a half-written library (the consensus path runs
-    through these)."""
-    src_mtime = max(os.path.getmtime(s) for s in srcs)
-    if os.path.exists(out_path) and \
-            os.path.getmtime(out_path) >= src_mtime:
-        return
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    tmp = out_path + f".tmp.{os.getpid()}"
-    subprocess.run(
-        ["g++", "-O2", "-shared", "-fPIC", *extra_flags,
-         "-o", tmp, srcs[0]],
-        check=True, capture_output=True, timeout=timeout)
-    os.replace(tmp, out_path)
 
 ST_OK, ST_TRAP, ST_BUDGET, ST_HOST = 0, 1, 2, 3
 
@@ -136,8 +112,7 @@ def _load():
             return _lib
         _tried = True
         try:
-            _build_lib([_SRC], _LIB, timeout=120)
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(build_lib([_SRC], "libwasmexec", timeout=120))
             lib.wasm_run.argtypes = [
                 ctypes.POINTER(_ProgramDesc), ctypes.c_int32, _i64p,
                 ctypes.c_int32, _HOST_CB, _MEM_CB, ctypes.c_void_p,
@@ -163,11 +138,14 @@ def _load_ext():
         try:
             import importlib.util
             import sysconfig
-            lib_path = _ext_lib_path()
             inc = sysconfig.get_paths()["include"]
-            _build_lib([_EXT_SRC, _SRC], lib_path,
-                       extra_flags=[f"-I{inc}",
-                                    f"-I{os.path.dirname(_SRC)}"])
+            # ABI-tagged: the extension links against a specific
+            # CPython's internals (unlike libwasmexec, which is
+            # Python-free), and the include path enters the hash too
+            lib_path = build_lib(
+                [_EXT_SRC, _SRC],
+                f"wasm_ext.{sys.implementation.cache_tag}",
+                extra_flags=[f"-I{inc}", f"-I{os.path.dirname(_SRC)}"])
             spec = importlib.util.spec_from_file_location(
                 "wasm_ext", lib_path)
             mod = importlib.util.module_from_spec(spec)

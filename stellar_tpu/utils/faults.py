@@ -6,7 +6,7 @@ device probe, the kernel dispatch, the device-array resolve. With no
 fault armed the call is a dict lookup on an empty dict; with one armed
 it misbehaves in a controlled, configurable way so
 ``tests/test_chaos_dispatch.py`` can drive the breaker/deadline/
-failover machinery on CPU, no broken tunnel required.
+failover machinery on CPU, no broken device required.
 
 Faults are armed programmatically (:func:`set_fault`) or via the
 ``STELLAR_TPU_FAULTS`` environment variable, e.g.::
@@ -17,7 +17,7 @@ Modes (``mode[:arg]``):
 
 * ``raise[:msg]``   — raise :class:`FaultInjected` on every call;
 * ``hang[:secs]``   — sleep ``secs`` (default 30) per call: the
-  dead-tunnel shape, where calls block instead of raising;
+  wedged-device shape, where calls block instead of raising;
 * ``flake[:k]``     — raise on every k-th call (default 2): an
   intermittently healthy link;
 * ``failn[:n]``     — raise on the first ``n`` calls (default 1), then

@@ -82,9 +82,9 @@ def append_jsonl_capped(path: str, rec: dict,
     """Size-bounded JSONL append with rotation: when ``path`` would
     grow past ``max_bytes``, shift ``path`` → ``path.1`` → ... →
     ``path.<keep>`` (the oldest generation is dropped) before
-    appending. Evidence streams written by unattended daemons
-    (``DEVICE_PROBES.jsonl`` from ``tools/device_watch.py``) keep the
-    recent history without ever filling the disk."""
+    appending. Evidence streams written by unattended runs (the soak
+    harness's event log) keep the recent history without ever filling
+    the disk."""
     import json
     import os
     line = json.dumps(rec) + "\n"

@@ -3,9 +3,10 @@
 splitting, and the sorted merge plan behind bucket merges.
 
 The library is compiled on first use with the system ``g++`` and cached
-under ``build/``; every entry point has a pure-Python fallback so the
-framework runs (slower) without a toolchain. Differential tests pin the
-two implementations together.
+under ``build/``, keyed by a hash of its source
+(:mod:`stellar_tpu.utils.native_build`); every entry point has a
+pure-Python fallback so the framework runs (slower) without a
+toolchain. Differential tests pin the two implementations together.
 """
 
 from __future__ import annotations
@@ -14,9 +15,10 @@ import ctypes
 import hashlib
 import os
 import struct
-import subprocess
 import threading
 from typing import List, Optional, Sequence, Tuple
+
+from stellar_tpu.utils.native_build import build_lib
 
 __all__ = ["available", "sha256", "hash_frames", "join_frames",
            "split_frames", "merge_plan"]
@@ -24,7 +26,6 @@ __all__ = ["available", "sha256", "hash_frames", "join_frames",
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "bucket_stream.cpp")
-_LIB = os.path.join(_REPO_ROOT, "build", "libbucketstream.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -38,13 +39,8 @@ def _load():
             return _lib
         _tried = True
         try:
-            if not os.path.exists(_LIB) or \
-                    os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-                os.makedirs(os.path.dirname(_LIB), exist_ok=True)
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-o", _LIB, _SRC],
-                    check=True, capture_output=True, timeout=120)
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(build_lib([_SRC], "libbucketstream",
+                                        timeout=120))
             lib.bs_sha256.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                       ctypes.c_char_p]
             lib.bs_hash_frames.argtypes = [

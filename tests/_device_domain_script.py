@@ -11,8 +11,9 @@ Compile budget: only ONE kernel shape is ever compiled (sub-chunk =
 bucket 8 // 4 devices = 2 rows), but jax compiles it once PER DEVICE
 (~55s each on CPU). Two mitigations keep this inside the tier-1
 budget: the per-device warm-up runs in parallel threads (XLA's C++
-compile releases the GIL), and a persistent compilation cache under
-/tmp makes every run after the first load instead of compile.
+compile releases the GIL), and the persistent compilation cache
+(``stellar_tpu.utils.jax_setup``) makes every run after the first load
+instead of compile.
 """
 
 import json
@@ -25,13 +26,15 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tests"))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+from stellar_tpu.utils.jax_setup import (  # noqa: E402
+    enable_compile_cache, force_cpu,
+)
+
+force_cpu()
+enable_compile_cache()
 
 import jax  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("DEVICE_DOMAIN_JAX_CACHE",
-                                 "/tmp/stellar_tpu_devchaos_jaxcache"))
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 import numpy as np  # noqa: E402

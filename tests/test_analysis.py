@@ -178,6 +178,16 @@ def test_scan_unroll_exact_counter():
     assert int(out.lo.min()) == 0 + sum(range(10))
 
 
+def test_tile_repeats_bounds():
+    """jnp.tile lowers to its own primitive on JAX 0.9 (the SHA-256
+    kernel uses it): bounds repeat with the values."""
+    import jax.numpy as jnp
+    interp, (out,) = _analyze(lambda a: jnp.tile(a, (2, 3)) * 2,
+                              _i32(2, 4), in_ranges=[(1, 5)])
+    assert out.shape == (4, 12)
+    assert int(out.lo.min()) == 2 and int(out.hi.max()) == 10
+
+
 def test_unsupported_primitive_is_loud():
     import jax.numpy as jnp
     import jax
@@ -569,7 +579,7 @@ def test_lock_lint_scope_covers_threaded_modules():
     assert "stellar_tpu/crypto/batch_verifier.py" in scope
     assert "stellar_tpu/utils/resilience.py" in scope
     assert "stellar_tpu/utils/metrics.py" in scope
-    assert "tools/device_watch.py" in scope
+    assert "stellar_tpu/parallel/batch_engine.py" in scope
     # ISSUE 4: the per-device quarantine registry mutates shared state
     # from dispatch threads and breaker callbacks — it must stay under
     # lock-discipline enforcement
@@ -1089,14 +1099,14 @@ def test_lockorder_allowlist_pinned():
         "stellar_tpu/utils/resilience.py":
             ["hold-and-block:WatchdogPool._loop.wait-untimed"],
         "stellar_tpu/utils/native.py":
-            ["hold-and-block:_load.subprocess"],
+            ["hold-and-block:_load.build_lib.subprocess"],
         "stellar_tpu/crypto/native_prep.py":
-            ["hold-and-block:_load.subprocess"],
+            ["hold-and-block:_load.build_lib.subprocess"],
         "stellar_tpu/crypto/native_verify.py":
-            ["hold-and-block:_load._build_lib.subprocess"],
+            ["hold-and-block:_load.build_lib.subprocess"],
         "stellar_tpu/soroban/native_wasm.py":
-            ["hold-and-block:_load._build_lib.subprocess",
-             "hold-and-block:_load_ext._build_lib.subprocess"],
+            ["hold-and-block:_load.build_lib.subprocess",
+             "hold-and-block:_load_ext.build_lib.subprocess"],
     }
 
 
@@ -1197,13 +1207,13 @@ def test_scope_sets_pinned():
         "stellar_tpu/utils/metrics.py",
         "stellar_tpu/utils/wire.py",
         "stellar_tpu/utils/native.py",
+        "stellar_tpu/utils/native_build.py",
         "stellar_tpu/utils/resilience.py",
         "stellar_tpu/utils/tracing.py",
         "stellar_tpu/utils/transfer_ledger.py",
         "stellar_tpu/utils/timeline.py",
         "stellar_tpu/utils/workers.py",
         "stellar_tpu/xdr/runtime.py",
-        "tools/device_watch.py",
     ])
     crypto_scope = {f for f in nondet.HOST_ORACLE_FILES
                     if f.startswith("stellar_tpu/crypto/")}

@@ -59,7 +59,7 @@ def test_mod_l_edge_digests():
     if not native_prep.available():
         pytest.skip("no toolchain")
     import ctypes
-    lib = ctypes.CDLL(native_prep._LIB)
+    lib = native_prep._load()
     u8p = ctypes.POINTER(ctypes.c_uint8)
     lib.ed25519_mod_l_raw.argtypes = [u8p, u8p]
 

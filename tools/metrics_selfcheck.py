@@ -11,7 +11,7 @@ no device, no jax dispatch, seconds of wall time:
 2. asserts the per-phase span sum reconciles to >= MIN_COVERAGE of the
    blocking root span, with every phase of
    ``batch_verifier.RESOLVE_PHASES`` present in the breakdown
-   (zero-count device phases included — the dead-tunnel completeness
+   (zero-count device phases included — the host-only completeness
    guarantee);
 3. renders the registry's Prometheus text exposition and parses every
    sample line back, requiring the span histograms to be present.

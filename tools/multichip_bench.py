@@ -1,12 +1,11 @@
 #!/usr/bin/env python
 """Multi-chip verify capture WITH fault-domain evidence (ISSUE 5
 satellite, closing the PR 4 ROADMAP item: "surface per-device health
-in MULTICHIP_r* capture runs").
+in multi-chip capture runs").
 
 Runs the production per-device dispatch path (``BatchVerifier`` over
 the auto mesh — one attributable sub-chunk dispatch per chip) and
-prints ONE JSON line that a ``MULTICHIP_r*`` record can embed
-verbatim. Alongside the p50 it carries everything needed to judge
+prints ONE JSON line that a multi-chip record can embed verbatim. Alongside the p50 it carries everything needed to judge
 whether the number is HONEST:
 
 - ``fault_domain``: per-device breaker states, quarantine onsets,
@@ -20,8 +19,8 @@ whether the number is HONEST:
 - ``verify_backend``: the served-count attribution bench.py uses — a
   silent host fallback can't claim a device number.
 
-Run by ``tools/device_watch.py`` during live windows (real mesh). For
-a CPU rehearsal: ``python tools/multichip_bench.py --force-cpu-devices
+On a 4-chip host: ``chiprun --chips 4 -- python tools/multichip_bench.py``.
+For a CPU rehearsal: ``python tools/multichip_bench.py --force-cpu-devices
 4 --sigs 64`` (each sub-chunk shape pays an XLA CPU compile — keep
 sigs small).
 """
@@ -33,17 +32,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-
-
-def force_cpu_devices(n: int) -> None:
-    """Point jax at n virtual CPU devices (mirrors __graft_entry__ /
-    tests/conftest.py; must run before any jax backend initializes)."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}"
-        ).strip()
 
 
 def fault_domain_evidence(verifier=None) -> dict:
@@ -82,19 +70,20 @@ def main() -> int:
     ap.add_argument("--force-cpu-devices", type=int, default=0,
                     help="rehearsal: N-way virtual CPU mesh")
     args = ap.parse_args()
+    from stellar_tpu.utils.jax_setup import enable_compile_cache, force_cpu
     if args.force_cpu_devices:
-        force_cpu_devices(args.force_cpu_devices)
+        force_cpu(args.force_cpu_devices)
+    enable_compile_cache()
 
     import numpy as np
 
-    from bench import _enable_compilation_cache, gen_sigs
+    from bench import gen_sigs
     from stellar_tpu.crypto import batch_verifier
     from stellar_tpu.crypto.batch_verifier import (
         BatchVerifier, _auto_mesh,
     )
     from stellar_tpu.utils import tracing
 
-    _enable_compilation_cache()
     mesh = _auto_mesh()
     n_devices = 1 if mesh is None else mesh.size
     items = gen_sigs(args.sigs)

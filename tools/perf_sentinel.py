@@ -32,8 +32,7 @@ sentinel compares the newest record (HEAD) against the previous one
   flags it for review instead of blocking the gate forever).
 
 A rule whose path is missing from the relevant record(s) is SKIPPED
-and listed — static (dead-tunnel) records legitimately lack live-only
-fields. The ``kernel_cost.*`` family is additionally VERSION-SCOPED:
+and listed — older records legitimately lack newer fields. The ``kernel_cost.*`` family is additionally VERSION-SCOPED:
 when the two records carry different ``kernel_cost.ledger_version``
 values (a deliberate window-scheme rework, bumped in
 tools/kernel_cost.py beside the docs/kernel_design.md §3 ledger), the
@@ -132,7 +131,7 @@ RULES = [
     ("transfer_ledger.reconciliation", "min_value", 0.95,
      "transfer ledger no longer reconciles engine byte accounting"),
     ("transfer_ledger.round_trips", "min_value", 1,
-     "transfer probe recorded no tunnel round trips"),
+     "transfer probe recorded no device round trips"),
     # scale-free: redundant bytes / shipped bytes — comparable across
     # probe-sized and live-sized windows, unlike absolute byte counts
     ("transfer_ledger.redundancy_frac", "max_increase_frac", 0.25,

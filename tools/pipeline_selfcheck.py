@@ -34,10 +34,10 @@ against the shared persistent cache):
    bit-identical to hashlib throughout (a stall is a delay, never a
    result change).
 
-Prints one JSON line whose top level carries the fields bench.py
-embeds as the dead-tunnel ``pipeline`` record section
-(``busy_frac`` / ``overlap_frac`` / ``reconciliation`` — the paths
-``tools/perf_sentinel.py`` gates); exit 0 = every check passed. See
+Prints one JSON line whose top level carries the ``pipeline`` record
+fields (``busy_frac`` / ``overlap_frac`` / ``reconciliation`` — the
+paths ``tools/perf_sentinel.py`` gates); exit 0 = every check passed.
+See
 ``docs/observability.md`` §9.
 """
 
@@ -67,16 +67,9 @@ PIPELINE_TRIES = 3
 def _env_setup() -> None:
     """CPU-only multi-device env — must run before jax imports (same
     shapes + persistent cache as the device-domain chaos driver)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags +
-            f" --xla_force_host_platform_device_count={N_DEV}").strip()
-    from stellar_tpu.utils.cpu_backend import force_cpu
-    force_cpu(compilation_cache_dir=os.environ.get(
-        "DEVICE_DOMAIN_JAX_CACHE",
-        "/tmp/stellar_tpu_devchaos_jaxcache"))
+    from stellar_tpu.utils.jax_setup import enable_compile_cache, force_cpu
+    force_cpu(N_DEV)
+    enable_compile_cache()
 
 
 def _corpus(i: int, n: int):

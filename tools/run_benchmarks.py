@@ -142,6 +142,8 @@ def render_table(results: dict) -> str:
 
 
 def main() -> int:
+    from stellar_tpu.utils.jax_setup import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="~30%% scale for smoke runs")

@@ -32,9 +32,9 @@ pays zero new XLA compiles). Without ``--smoke`` the flood runs for
 soaks too.
 
 Per-phase events append to a size-capped JSONL
-(``utils.logging.append_jsonl_capped`` — same 4 MB + 1 generation
-rotation as ``DEVICE_PROBES.jsonl``), so long soaks can't fill the
-disk. Prints one JSON record; exit 0 = every check passed.
+(``utils.logging.append_jsonl_capped`` — 4 MB + 1 generation
+rotation), so long soaks can't fill the disk. Prints one JSON record;
+exit 0 = every check passed.
 """
 
 import argparse
@@ -59,19 +59,12 @@ SMOKE_SCP_P99_BOUND_MS = 8000.0
 
 
 def _env_setup(real_device: bool) -> None:
-    """CPU-only multi-device env — must run before jax imports."""
-    if real_device:
-        return
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags +
-            f" --xla_force_host_platform_device_count={N_DEV}").strip()
-    from stellar_tpu.utils.cpu_backend import force_cpu
-    force_cpu(compilation_cache_dir=os.environ.get(
-        "DEVICE_DOMAIN_JAX_CACHE",
-        "/tmp/stellar_tpu_devchaos_jaxcache"))
+    """CPU-only multi-device env unless ``real_device`` — must run
+    before jax imports."""
+    from stellar_tpu.utils.jax_setup import enable_compile_cache, force_cpu
+    if not real_device:
+        force_cpu(N_DEV)
+    enable_compile_cache()
 
 
 def ramp_schedule(rounds: int, base_count: int) -> list:

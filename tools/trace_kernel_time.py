@@ -8,8 +8,7 @@ from subtracting a dispatch floor.  Usage:
 
 Prints one JSON line: per-device-process busy time (union of complete
 event intervals, so nested events are not double-counted) divided by
-``n_iters`` (the number of traced kernel invocations; device_watch
-traces 3).
+``n_iters`` (the number of traced kernel invocations).
 """
 import gzip
 import json

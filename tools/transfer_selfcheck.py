@@ -50,16 +50,9 @@ MIN_RECONCILE = 0.95
 def _env_setup() -> None:
     """CPU-only multi-device env — must run before jax imports (same
     shapes + persistent cache as the device-domain chaos driver)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags +
-            f" --xla_force_host_platform_device_count={N_DEV}").strip()
-    from stellar_tpu.utils.cpu_backend import force_cpu
-    force_cpu(compilation_cache_dir=os.environ.get(
-        "DEVICE_DOMAIN_JAX_CACHE",
-        "/tmp/stellar_tpu_devchaos_jaxcache"))
+    from stellar_tpu.utils.jax_setup import enable_compile_cache, force_cpu
+    force_cpu(N_DEV)
+    enable_compile_cache()
 
 
 def _corpus(n: int):
