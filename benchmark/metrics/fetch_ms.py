@@ -1,0 +1,11 @@
+"""``verify.fetch`` span time per call, root-attributed: the
+``span.attr.verify.fetch`` timer, which counts a phase only once its
+blocking root has closed."""
+
+
+def read(ctx):
+    calls = len(ctx["calls"])
+    tot = ctx["spans"].get("span.attr.verify.fetch")
+    if not calls or tot is None:
+        return None
+    return tot["sum_ms"] / calls
