@@ -161,39 +161,41 @@ class Ed25519Workload(Workload):
                ) -> Tuple[np.ndarray, tuple]:
         n = len(items)
         ok = np.ones(n, dtype=bool)
-        # one frombuffer over joined bytes instead of three numpy row
-        # writes per item — the per-item version was the single
-        # biggest host-prep cost at 2k-signature batches
-        msgs = []
-        pk_parts = []
-        sig_parts = []
-        z32, z64 = bytes(32), bytes(64)
-        for i, (pk, msg, sig) in enumerate(items):
-            if len(pk) != 32 or len(sig) != 64:
-                ok[i] = False
-                pk_parts.append(z32)
-                sig_parts.append(z64)
-                msgs.append(b"")
-            else:
-                pk_parts.append(pk)
-                sig_parts.append(sig)
-                msgs.append(msg)
-        a = np.frombuffer(b"".join(pk_parts),
-                          dtype=np.uint8).reshape(n, 32)
-        sig_mat = np.frombuffer(b"".join(sig_parts),
-                                dtype=np.uint8).reshape(n, 64)
-        r = np.ascontiguousarray(sig_mat[:, :32])
-        s = np.ascontiguousarray(sig_mat[:, 32:])
-        # h = SHA512(R||A||M) mod L — native multithreaded C++
-        h = native_prep.prep_batch(r, a, msgs)
-        # host policy checks (libsodium order: s canonical, small-order R/A,
-        # canonical A)
-        ok &= _lt_le_bytes(s, _L_BYTES)
-        ok &= ~_small_order_mask(r)
-        ok &= ~_small_order_mask(a)
-        a_masked = a.copy()
-        a_masked[:, 31] &= 0x7F
-        ok &= _lt_le_bytes(a_masked, _P_BYTES)
+        with tracing.span(f"{self.span_ns}.prep.challenge"):
+            # one frombuffer over joined bytes instead of three numpy
+            # row writes per item — the per-item version was the
+            # single biggest host-prep cost at 2k-signature batches
+            msgs = []
+            pk_parts = []
+            sig_parts = []
+            z32, z64 = bytes(32), bytes(64)
+            for i, (pk, msg, sig) in enumerate(items):
+                if len(pk) != 32 or len(sig) != 64:
+                    ok[i] = False
+                    pk_parts.append(z32)
+                    sig_parts.append(z64)
+                    msgs.append(b"")
+                else:
+                    pk_parts.append(pk)
+                    sig_parts.append(sig)
+                    msgs.append(msg)
+            a = np.frombuffer(b"".join(pk_parts),
+                              dtype=np.uint8).reshape(n, 32)
+            sig_mat = np.frombuffer(b"".join(sig_parts),
+                                    dtype=np.uint8).reshape(n, 64)
+            r = np.ascontiguousarray(sig_mat[:, :32])
+            s = np.ascontiguousarray(sig_mat[:, 32:])
+            # h = SHA512(R||A||M) mod L — native multithreaded C++
+            h = native_prep.prep_batch(r, a, msgs)
+        with tracing.span(f"{self.span_ns}.prep.gate"):
+            # host policy checks (libsodium order: s canonical,
+            # small-order R/A, canonical A)
+            ok &= _lt_le_bytes(s, _L_BYTES)
+            ok &= ~_small_order_mask(r)
+            ok &= ~_small_order_mask(a)
+            a_masked = a.copy()
+            a_masked[:, 31] &= 0x7F
+            ok &= _lt_le_bytes(a_masked, _P_BYTES)
         return ok, (a, r, s, h)
 
     def pad_rows(self) -> tuple:
@@ -241,7 +243,8 @@ class Ed25519HotWorkload(Ed25519Workload):
     def encode(self, items: Sequence[tuple]
                ) -> Tuple[np.ndarray, tuple]:
         ok, (_a, r, s, h) = super().encode([it for it, _t in items])
-        tables = np.stack([t for _it, t in items])
+        with tracing.span(f"{self.span_ns}.prep.gather"):
+            tables = np.stack([t for _it, t in items])
         return ok, (tables, r, s, h)
 
     def pad_rows(self) -> tuple:
@@ -306,7 +309,9 @@ class BatchVerifier(BatchEngine):
         # the partition (cache traffic + first-sight table builds) is
         # host PREP work: it rides the prep phase span so the blocking
         # root's attribution stays >= 95% covered (METRICS_EXPORT_OK)
-        with tracing.span(f"{self._span_ns}.prep"):
+        ns = self._span_ns
+        with tracing.span(f"{ns}.prep"), \
+                tracing.span(f"{ns}.prep.partition"):
             for i, it in enumerate(items):
                 pk = it[0]
                 tab = cache.lookup(pk) if len(pk) == 32 else None
@@ -323,9 +328,10 @@ class BatchVerifier(BatchEngine):
                     # this very batch — hits; THIS row still rides
                     # cold (its verdict needs the full decompress
                     # gate the cold kernel carries)
-                    built = signer_tables.build_signer_table(pk)
-                    if built is not None:
-                        cache.install(pk, built)
+                    with tracing.span(f"{ns}.table_build"):
+                        built = signer_tables.build_signer_table(pk)
+                        if built is not None:
+                            cache.install(pk, built)
         if not hot_items:
             return super().submit(items, trace_ids=trace_ids)
         registry.meter(

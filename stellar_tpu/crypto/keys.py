@@ -23,6 +23,7 @@ from stellar_tpu.crypto import ed25519_ref as _ref
 from stellar_tpu.crypto import strkey as _strkey
 from stellar_tpu.crypto.sha import sha256
 from stellar_tpu.utils.cache import RandomEvictionCache
+from stellar_tpu.utils.tracing import span
 
 __all__ = [
     "SecretKey", "PublicKey", "verify_sig", "cached_verify_sig",
@@ -200,15 +201,20 @@ def verify_sig(pk, msg: bytes, sig: bytes) -> bool:
     raw = pk.raw if isinstance(pk, PublicKey) else bytes(pk)
     if len(sig) != 64 or len(raw) != 32:
         return False
+    # called once per signature during apply: a hit records no span,
+    # so the probe is recorded only when it misses
+    probe = span.deferred("keys.probe")
     key = _cache_key(raw, msg, sig)
     with _cache_lock:
         got = _verify_cache.maybe_get(key)
     if got is not None:
         return got
+    probe.keep()
     fn = _backend or _ref.verify
     ok = bool(fn(raw, msg, sig))
-    with _cache_lock:
-        _verify_cache.put(key, ok)
+    with span("keys.seed"):
+        with _cache_lock:
+            _verify_cache.put(key, ok)
     return ok
 
 
@@ -271,13 +277,14 @@ def batch_verify_into_cache(items) -> None:
     prefetch, then the per-signer logic runs unchanged
     (reference boundary: ``PubKeyUtils::verifySig`` cache,
     ``SecretKey.cpp:318-338``)."""
-    # hash outside the lock; keep the key alongside the triple
-    keyed = [(_cache_key(pk, msg, sig), pk, msg, sig)
-             for pk, msg, sig in items
-             if len(pk) == 32 and len(sig) == 64]
-    with _cache_lock:
-        todo = [(k, pk, msg, sig) for k, pk, msg, sig in keyed
-                if _verify_cache.maybe_get(k) is None]
+    with span("keys.probe"):
+        # hash outside the lock; keep the key alongside the triple
+        keyed = [(_cache_key(pk, msg, sig), pk, msg, sig)
+                 for pk, msg, sig in items
+                 if len(pk) == 32 and len(sig) == 64]
+        with _cache_lock:
+            todo = [(k, pk, msg, sig) for k, pk, msg, sig in keyed
+                    if _verify_cache.maybe_get(k) is None]
     if not todo:
         return
     if len(todo) < MIN_DEVICE_BATCH:
@@ -307,7 +314,7 @@ def batch_verify_into_cache(items) -> None:
             # policy gate in Python as always) spreads the equation
             # checks across cores where the host has them
             results = _host_oracle_batch(todo)
-    with _cache_lock:
+    with span("keys.seed"), _cache_lock:
         for (k, _, _, _), ok in zip(todo, results):
             _verify_cache.put(k, bool(ok))
 

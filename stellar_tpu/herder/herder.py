@@ -35,6 +35,7 @@ from stellar_tpu.ledger.ledger_txn import LedgerTxn
 from stellar_tpu.scp import SCP, EnvelopeState, SCPDriver, ValidationLevel
 from stellar_tpu.scp.slot import BALLOT_PROTOCOL_TIMER, NOMINATION_TIMER
 from stellar_tpu.utils.timer import VirtualClock, VirtualTimer
+from stellar_tpu.utils.tracing import span
 from stellar_tpu.xdr.ledger import StellarValue, basic_stellar_value
 from stellar_tpu.xdr.runtime import Packer, from_bytes, to_bytes
 from stellar_tpu.xdr.scp import SCPEnvelope, SCPQuorumSet, SCPStatement
@@ -334,10 +335,11 @@ class Herder:
         service-side failure falls back to the direct path — the
         decision is bit-identical on every route, so the service can
         only ever change latency, never validity."""
-        payload = scp_envelope_sign_payload(self.network_id,
-                                            env.statement)
-        pk = env.statement.nodeID.value
-        got = cached_verify_sig(pk, payload, env.signature)
+        with span("herder.payload"):
+            payload = scp_envelope_sign_payload(self.network_id,
+                                                env.statement)
+            pk = env.statement.nodeID.value
+            got = cached_verify_sig(pk, payload, env.signature)
         if got is not None:
             return got
         # shared adopter block (service_verified): bounded wait +

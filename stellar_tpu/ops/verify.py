@@ -148,8 +148,9 @@ def dsm_stage(s_bytes, h_bytes, a_neg):
     of the kernel (tools/kernel_cost.py accounts cost per stage; the
     limb layout, window scheme, and MAC ledger live in
     docs/kernel_design.md). Radix-32 batched-affine since PR 13."""
-    return ed.double_scalarmult(
-        signed_digits32_dev(s_bytes), signed_digits32_dev(h_bytes), a_neg)
+    with jax.named_scope("dsm"):
+        return ed.double_scalarmult(signed_digits32_dev(s_bytes),
+                                    signed_digits32_dev(h_bytes), a_neg)
 
 
 def verify_kernel(a_bytes, r_bytes, s_bytes, h_bytes):
@@ -169,9 +170,12 @@ def verify_kernel(a_bytes, r_bytes, s_bytes, h_bytes):
       bit-identical to libsodium because s >= L never reaches a verdict
       (host canonical-s gate).
     """
-    ok, a = ed.decompress(a_bytes)
+    with jax.named_scope("decompress"):
+        ok, a = ed.decompress(a_bytes)
     rprime = dsm_stage(s_bytes, h_bytes, ed.negate(a))
-    return ok & ed.compress_equals(rprime, r_bytes)
+    with jax.named_scope("compress_compare"):
+        eq = ed.compress_equals(rprime, r_bytes)
+    return ok & eq
 
 
 def dsm_stage_hot(s_bytes, h_bytes, a_table):
@@ -180,9 +184,10 @@ def dsm_stage_hot(s_bytes, h_bytes, a_table):
     is the batch-LEADING (batch, 128, 3, 20) int16 operand exactly as
     the signer-table cache ships it; the limb layout wants batch
     TRAILING, so the one transpose lives here at the stage boundary."""
-    tab = jnp.moveaxis(a_table, 0, -1)  # (128, 3, 20, batch)
-    return ed.double_scalarmult_hot(
-        signed_digits256_dev(s_bytes), signed_digits256_dev(h_bytes), tab)
+    with jax.named_scope("dsm"):
+        tab = jnp.moveaxis(a_table, 0, -1)  # (128, 3, 20, batch)
+        return ed.double_scalarmult_hot(signed_digits256_dev(s_bytes),
+                                        signed_digits256_dev(h_bytes), tab)
 
 
 def verify_kernel_hot(a_table, r_bytes, s_bytes, h_bytes):
@@ -205,7 +210,8 @@ def verify_kernel_hot(a_table, r_bytes, s_bytes, h_bytes):
       both paths accept, which the differential suite pins per bucket.
     """
     rprime = dsm_stage_hot(s_bytes, h_bytes, a_table)
-    return ed.compress_equals(rprime, r_bytes)
+    with jax.named_scope("compress_compare"):
+        return ed.compress_equals(rprime, r_bytes)
 
 
 def verify_kernel_sharded(mesh, axis_name="batch"):

@@ -854,37 +854,41 @@ class BatchEngine:
         cache = residency.resident_cache
         placed = []
         donatable = _donation_active()
-        for a in arrays:
-            fp = residency.fingerprint(a)
-            hit = cache.get(fp, a, pkey)
-            if hit is not None:
-                transfer_ledger.record_resident_hit(tok, a,
-                                                    device=dev_idx)
-                with self._stats_lock:
-                    self.resident_hits += 1
-                placed.append(hit)
-                donatable = False
-                continue
-            put = jax.device_put(a, dest) if dest is not None \
-                else jax.device_put(a)
-            # transfer ledger: the device_put IS the h2d upload; the
-            # engine's own shape-derived tally is the reconciliation
-            # oracle (tools/transfer_selfcheck.py). The precomputed
-            # fingerprint is forwarded only when residency actually
-            # computed one — an operand over the RESIDENCY size cap
-            # must still be fingerprinted under the ledger's OWN cap
-            # (TRANSFER_LEDGER_FP_MAX_BYTES), or the redundancy
-            # detector would silently lose exactly the mid-size
-            # constants between the two knobs
-            if fp is not None:
-                transfer_ledger.record_h2d(tok, a, device=dev_idx,
-                                           fp=fp)
-            else:
-                transfer_ledger.record_h2d(tok, a, device=dev_idx)
-            self._ship_accounting((a,))
-            if cache.put(fp, a, pkey, put):
-                donatable = False
-            placed.append(put)
+        fresh = hits = 0
+        with tracing.span(f"{self._span_ns}.place") as sp:
+            for a in arrays:
+                fp = residency.fingerprint(a)
+                hit = cache.get(fp, a, pkey)
+                if hit is not None:
+                    transfer_ledger.record_resident_hit(tok, a,
+                                                        device=dev_idx)
+                    with self._stats_lock:
+                        self.resident_hits += 1
+                    placed.append(hit)
+                    donatable = False
+                    hits += 1
+                    continue
+                put = jax.device_put(a, dest) if dest is not None \
+                    else jax.device_put(a)
+                # transfer ledger: the device_put IS the h2d upload; the
+                # engine's own shape-derived tally is the reconciliation
+                # oracle (tools/transfer_selfcheck.py). The precomputed
+                # fingerprint is forwarded only when residency actually
+                # computed one — an operand over the RESIDENCY size cap
+                # must still be fingerprinted under the ledger's OWN cap
+                # (TRANSFER_LEDGER_FP_MAX_BYTES), or the redundancy
+                # detector would silently lose exactly the mid-size
+                # constants between the two knobs
+                if fp is not None:
+                    transfer_ledger.record_h2d(tok, a, device=dev_idx,
+                                               fp=fp)
+                else:
+                    transfer_ledger.record_h2d(tok, a, device=dev_idx)
+                fresh += self._ship_accounting((a,))
+                if cache.put(fp, a, pkey, put):
+                    donatable = False
+                placed.append(put)
+            sp.set_attrs(h2d_bytes=fresh, resident_hits=hits)
         return tuple(placed), donatable
 
     def _coalesced_upload(self, arrays: tuple, tok):
@@ -1245,21 +1249,23 @@ class BatchEngine:
                 audit_attrs["traces"] = atr
             with tracing.span(f"{self._span_ns}.audit", **audit_attrs), \
                     pipeline_timeline.host_phase(ptok, "audit"):
-                material = b"".join(x[lo:hi].tobytes()
-                                    for x in encoded_c)
-                eligible = [i for i in range(hi - lo)
-                            if gate_c[lo + i]]
-                idxs = audit_mod.sample_rows(material, eligible,
-                                             AUDIT_RATE)
+                with tracing.span(f"{self._span_ns}.audit.sample"):
+                    material = b"".join(x[lo:hi].tobytes()
+                                        for x in encoded_c)
+                    eligible = [i for i in range(hi - lo)
+                                if gate_c[lo + i]]
+                    idxs = audit_mod.sample_rows(material, eligible,
+                                                 AUDIT_RATE)
                 if not idxs:
                     return True
                 registry.counter(self._ns + ".audit.sampled").inc(
                     len(idxs))
-                want = plugin.host_result(
-                    [items[gl + i] for i in idxs])
-                got_comp = np.stack([np.asarray(vals[i])
-                                     for i in idxs])
-                clean = bool((np.asarray(want) == got_comp).all())
+                with tracing.span(f"{self._span_ns}.audit.oracle"):
+                    want = plugin.host_result(
+                        [items[gl + i] for i in idxs])
+                    got_comp = np.stack([np.asarray(vals[i])
+                                         for i in idxs])
+                    clean = bool((np.asarray(want) == got_comp).all())
             # verdict lands in both evidence streams: the per-device
             # health registry (MULTICHIP fault-domain evidence) and
             # the flight recorder (visible in dumps near the spans)

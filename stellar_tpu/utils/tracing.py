@@ -29,6 +29,15 @@ Model:
   ``crypto/batch_verifier.py`` wires all the triggers) so the spans
   leading into a failure survive to be read from the ``spans`` admin
   route. See ``docs/observability.md``.
+* **profiler capture**: while a JAX profiler session collects, each
+  span also opens a profiler TraceMe (``jax.profiler.TraceAnnotation``)
+  named exactly as its recorder record (``span.<name>``), so an
+  xprof/Perfetto capture holds the program phases on the device
+  trace's own clock. With no session collecting, the cost is one
+  enabled-check per span; jax is never imported from here.
+* **deferred spans** (:meth:`span.deferred`): a span that is recorded
+  only if its owner calls ``keep()`` — a cache probe whose hits must
+  stay span-free times its misses this way.
 * **span phase families**: ``verify.*`` phases attribute one blocking
   resolve (``batch_verifier.RESOLVE_PHASES``); ``service.dispatch`` /
   ``service.resolve`` wrap the resident verify service's continuous-
@@ -50,6 +59,7 @@ from __future__ import annotations
 
 import itertools
 import logging
+import sys
 import threading
 import time
 from collections import deque
@@ -77,6 +87,20 @@ _EPOCH = time.perf_counter()
 
 def _now_ms() -> float:
     return (time.perf_counter() - _EPOCH) * 1000.0
+
+
+# The profiler's TraceMe class (jax.profiler.TraceAnnotation), found the
+# first time a span opens after jax is loaded: no profiler session can
+# collect before that, and a jax-free process never imports it.
+_trace_me = None
+
+
+def _load_trace_me():
+    global _trace_me
+    prof = sys.modules.get("jax.profiler")
+    if prof is not None:
+        _trace_me = prof.TraceAnnotation
+    return _trace_me
 
 
 def _stack() -> list:
@@ -512,7 +536,7 @@ class span:
 
     _PREFIX = "span"
     __slots__ = ("name", "attrs", "span_id", "parent_id", "_t0",
-                 "_rec", "_collect", "_collected")
+                 "_rec", "_collect", "_collected", "_tm")
 
     def __init__(self, name: str, _collect=None, **attrs):
         self.name = name
@@ -532,10 +556,18 @@ class span:
             self._rec["attrs"] = dict(self.attrs)
         st.append(self)
         flight_recorder.start_span(self._rec)
+        tm = _trace_me or _load_trace_me()
+        if tm is not None and tm.is_enabled():
+            self._tm = tm(self._rec["name"])
+            self._tm.__enter__()
+        else:
+            self._tm = None
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        if self._tm is not None:
+            self._tm.__exit__(None, None, None)
         if self._rec.get("abandoned"):
             # already swept into the ring by an outer span's (or
             # anchor's) defensive pop — a late __exit__ (closed
@@ -593,6 +625,42 @@ class span:
         rec = getattr(self, "_rec", None)
         if rec is not None and rec.get("dur_ms") is None:
             flight_recorder.abandon_span(rec)
+
+    def set_attrs(self, **attrs) -> None:
+        """Add attributes known only inside the span (bytes moved,
+        cache hits) to its record."""
+        self._rec.setdefault("attrs", {}).update(attrs)
+
+    @staticmethod
+    def deferred(name: str) -> "_DeferredSpan":
+        """``sp = span.deferred("keys.probe"); ...; sp.keep()`` — a
+        span that starts when made and is recorded only if ``keep()``
+        runs, ending it there. Dropped unkept, it cost one clock read:
+        a cache probe whose hits must stay span-free records only its
+        misses. A kept span lands in the ``span.<name>`` timer and the
+        recorder, parented under the innermost live span, but it was
+        never on the live stack: it parents nothing, folds into no
+        collector and reaches no profiler capture."""
+        return _DeferredSpan(name)
+
+
+class _DeferredSpan:
+    __slots__ = ("name", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._t0 = time.perf_counter()
+
+    def keep(self) -> None:
+        dt_ms = (time.perf_counter() - self._t0) * 1000.0
+        name = f"span.{self.name}"
+        registry.timer(name).update_ms(dt_ms)
+        st = _stack()
+        flight_recorder.finish_span({
+            "id": next(_ids), "parent": st[-1].span_id if st else None,
+            "name": name, "thread": threading.current_thread().name,
+            "start_ms": round((self._t0 - _EPOCH) * 1000.0, 3),
+            "dur_ms": round(dt_ms, 3)})
 
 
 class zone(span):
