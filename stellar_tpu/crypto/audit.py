@@ -14,8 +14,12 @@ replica could quarantine its device (and change its serving backend)
 on a batch where another did not — a latency divergence that is fine,
 but it must never come from per-process randomness that the nondet
 lint exists to ban. So indices are derived counter-mode from
-SHA-256 of the chunk's raw bytes: same batch → same sample, on every
-node, in every process. No clocks, no RNG state, no hash salts.
+SHA-256 of the chunk's encoded bytes, as the workload plugin's
+``audit_material`` selects them: all of them by default; the hot
+verify path leaves out each row's signer table, which is a function
+of the public key that ``h = SHA-512(R‖A‖M) mod L`` already commits
+to. Same batch → same sample, on every node, in every process. No
+clocks, no RNG state, no hash salts.
 """
 
 from __future__ import annotations
@@ -29,7 +33,7 @@ __all__ = ["sample_indices", "sample_rows", "verdict_record",
 
 def sample_indices(material: bytes, n: int, rate: float) -> List[int]:
     """Indices in ``[0, n)`` to audit, derived deterministically from
-    ``material`` (the chunk's raw bytes).
+    ``material`` (the chunk's encoded bytes).
 
     ``rate <= 0`` disables the audit (empty sample). Otherwise the
     sample size is ``max(1, int(n * rate))`` — at least one row per
@@ -69,8 +73,8 @@ def sample_rows(material: bytes, eligible_rows: Sequence[int],
     Rows the host policy gate already rejected compare ``False ==
     False`` against the oracle no matter what the device returned —
     sampling them would be vacuous, and since the sample is derived
-    from the exact bytes the device holds, a corrupting chip could
-    even predict such a blind spot. Restricting to eligible rows keeps
+    from bytes the device holds, a corrupting chip could even predict
+    such a blind spot. Restricting to eligible rows keeps
     every drawn sample a REAL cross-check; the eligibility mask is
     host-computed and deterministic, so replicas still agree.
 

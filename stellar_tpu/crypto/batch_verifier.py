@@ -247,6 +247,12 @@ class Ed25519HotWorkload(Ed25519Workload):
             tables = np.stack([t for _it, t in items])
         return ok, (tables, r, s, h)
 
+    def audit_material(self, encoded: tuple, lo: int, hi: int) -> bytes:
+        # the table is a function of the pubkey, and h = SHA-512(R‖A‖M)
+        # mod L already commits to it: (R, S, h) seed the sample as
+        # deterministically, without hashing ~15 KB of table per row
+        return super().audit_material(encoded[1:], lo, hi)
+
     def pad_rows(self) -> tuple:
         return (_PAD_TABLE, _PAD_R, _PAD_S, _PAD_H)
 

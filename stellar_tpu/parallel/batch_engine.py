@@ -604,7 +604,8 @@ class Workload:
     The engine owns dispatch, fault domains, audit SAMPLING, failover,
     and instrumentation; the plugin owns everything the work MEANS:
     encoding, the kernel, the host oracle, and result composition.
-    Subclasses override every method below (the base raises).
+    Subclasses override every method from ``encode`` on (the base
+    raises); the two hooks before it have defaults.
 
     Contracts:
 
@@ -640,6 +641,16 @@ class Workload:
         items — e.g. the hot-signer table cache, whose resident tables
         must never outlive the audit that caught the batch they
         served — evict it here. Default: nothing to evict."""
+
+    def audit_material(self, encoded: tuple, lo: int, hi: int) -> bytes:
+        """Hook: the bytes that seed the audit sample of rows
+        ``lo:hi`` of ``encode``'s arrays. They must be a function of
+        the batch content alone, so replicas fed the same batch audit
+        the same rows. Default: every encoded array's rows, joined. A
+        plugin whose arrays carry data derived from what another array
+        already commits to may leave it out: it adds hashing time, not
+        determinism."""
+        return b"".join(x[lo:hi].tobytes() for x in encoded)
 
     def encode(self, items: Sequence) -> Tuple[np.ndarray, tuple]:
         """Host prep: ``items`` -> ``(gate, arrays)``. ``gate`` is a
@@ -1236,12 +1247,13 @@ class BatchEngine:
             re-compute a content-seeded sample through the host oracle
             and compare against the COMPOSED result (the quantity
             pinned bit-identical to the plugin's oracle). The sample
-            material is the chunk's own encoded bytes — the exact
-            bytes the device received. Only rows that PASSED the gate
-            are sampled: a gate-failed row's outcome never reads
-            device bits, so auditing it would be vacuous (and a
-            device-predictable blind spot). True = clean (or nothing
-            to audit)."""
+            is seeded from the part's encoded rows as the plugin's
+            ``audit_material`` selects them (a function of the batch
+            content; the span's ``material_bytes`` attr is its size).
+            Only rows that PASSED the gate are sampled: a gate-failed
+            row's outcome never reads device bits, so auditing it
+            would be vacuous (and a device-predictable blind spot).
+            True = clean (or nothing to audit)."""
             gl, gh = sl.start + lo, sl.start + hi
             audit_attrs = {"device": di}
             atr = _part_traces(gl, gh)
@@ -1249,11 +1261,11 @@ class BatchEngine:
                 audit_attrs["traces"] = atr
             with tracing.span(f"{self._span_ns}.audit", **audit_attrs), \
                     pipeline_timeline.host_phase(ptok, "audit"):
-                with tracing.span(f"{self._span_ns}.audit.sample"):
-                    material = b"".join(x[lo:hi].tobytes()
-                                        for x in encoded_c)
-                    eligible = [i for i in range(hi - lo)
-                                if gate_c[lo + i]]
+                with tracing.span(
+                        f"{self._span_ns}.audit.sample") as sp:
+                    material = plugin.audit_material(encoded_c, lo, hi)
+                    sp.set_attrs(material_bytes=len(material))
+                    eligible = np.flatnonzero(gate_c[lo:hi]).tolist()
                     idxs = audit_mod.sample_rows(material, eligible,
                                                  AUDIT_RATE)
                 if not idxs:
