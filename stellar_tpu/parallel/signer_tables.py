@@ -22,6 +22,9 @@ sibling this cache is keyed and byte-bounded exactly like:
   Application like every dispatch knob) bounds host retention with
   recency eviction: hot validators re-hit every batch and stay, one-off
   signers churn through the tail;
+* a table evicted by the budget before it served a single lookup
+  counts in ``evicted_unused``: a build whose cost bought nothing,
+  the waste a long tail of one-off signers makes;
 * :func:`SignerTableCache.evict` exists for the AUDIT path — a
   ``corrupt-device`` conviction while a cached table served the batch
   evicts that signer's entry (a poisoned resident table must never
@@ -111,8 +114,8 @@ def build_signer_table(pk: bytes) -> Optional[np.ndarray]:
     """Host-build the hot-path table for one pubkey: decompress, negate
     (the kernel computes s*B + h*(-A)), derive the 128 affine cached
     rows with the pure-Python oracle (incremental chain + ONE batched
-    inversion, ~1-2 ms), and pack canonical 13-bit limbs as
-    ``(TABLE_ENTRIES, 3, 20) int16``. Returns None when the pubkey has
+    inversion, about 3 ms on a TPU v5e host), and pack canonical
+    13-bit limbs as ``(TABLE_ENTRIES, 3, 20) int16``. Returns None when the pubkey has
     the wrong length or fails decompression — such a signer is never
     cached and never dispatches hot (the cold path's host gates and
     decompress stage handle it)."""
@@ -130,6 +133,16 @@ def build_signer_table(pk: bytes) -> Optional[np.ndarray]:
     return out
 
 
+class _Entry:
+    """One cached table and whether it has served a lookup yet."""
+
+    __slots__ = ("table", "served")
+
+    def __init__(self, table: np.ndarray):
+        self.table = table
+        self.served = False
+
+
 class SignerTableCache:
     """Process-wide LRU of per-pubkey hot-path tables, byte-bounded.
 
@@ -142,13 +155,14 @@ class SignerTableCache:
     def __init__(self, max_bytes: int = DEFAULT_CACHE_BYTES,
                  enabled: bool = _ENABLED_DEFAULT):
         self._lock = threading.Lock()
-        self._entries: OrderedDict = OrderedDict()  # fp -> table
+        self._entries: OrderedDict = OrderedDict()  # fp -> _Entry
         self._max_bytes = max(0, int(max_bytes))
         self._enabled = bool(enabled)
         self._hits = 0
         self._misses = 0
         self._installs = 0
         self._evictions = 0
+        self._evicted_unused = 0
         self._audit_evictions = 0
 
     # ---------------- knobs ----------------
@@ -187,9 +201,10 @@ class SignerTableCache:
                 registry.counter(f"{_NS}.misses").inc()
                 return None
             self._entries.move_to_end(fp)
+            hit.served = True
             self._hits += 1
         registry.counter(f"{_NS}.hits").inc()
-        return hit
+        return hit.table
 
     def install(self, pk: bytes, table: np.ndarray) -> bool:
         """Retain one freshly-built table; returns True when cached.
@@ -201,7 +216,7 @@ class SignerTableCache:
         fp = signer_fingerprint(pk)
         with self._lock:
             self._entries.pop(fp, None)
-            self._entries[fp] = table
+            self._entries[fp] = _Entry(table)
             self._installs += 1
             self._evict_locked()
         registry.counter(f"{_NS}.installs").inc()
@@ -224,9 +239,12 @@ class SignerTableCache:
     def _evict_locked(self) -> None:
         while len(self._entries) * TABLE_BYTES > self._max_bytes \
                 and self._entries:
-            self._entries.popitem(last=False)
+            _fp, entry = self._entries.popitem(last=False)
             self._evictions += 1
             registry.counter(f"{_NS}.evictions").inc()
+            if not entry.served:
+                self._evicted_unused += 1
+                registry.meter(f"{_NS}.evicted_unused").mark()
 
     # ---------------- introspection ----------------
 
@@ -243,6 +261,7 @@ class SignerTableCache:
                 "misses": self._misses,
                 "installs": self._installs,
                 "evictions": self._evictions,
+                "evicted_unused": self._evicted_unused,
                 "audit_evictions": self._audit_evictions,
             }
 
@@ -260,6 +279,7 @@ class SignerTableCache:
             self._misses = 0
             self._installs = 0
             self._evictions = 0
+            self._evicted_unused = 0
             self._audit_evictions = 0
 
 

@@ -174,6 +174,33 @@ def test_audit_evict_drops_exactly_one_signer():
     assert cache.lookup(_pk(1)) is not None
 
 
+def test_evicted_unused_counts_tables_that_never_served():
+    """A (hit) and B (never looked up) fill a 2-table budget; C evicts
+    B, the LRU, which counts as evicted unused. An audit eviction of a
+    never-served table does not."""
+    from stellar_tpu.utils.metrics import registry
+    meter = registry.meter("crypto.verify.signer_table.evicted_unused")
+    before = meter.count
+    cache = st.SignerTableCache(max_bytes=2 * st.TABLE_BYTES)
+    a, b, c = _fake_table(), _fake_table(), _fake_table()
+    cache.install(_pk(0), a)
+    cache.install(_pk(1), b)
+    assert cache.lookup(_pk(0)) is a           # the table, not its entry
+    cache.install(_pk(2), c)
+    snap = cache.snapshot()
+    assert snap["evictions"] == 1 and snap["evicted_unused"] == 1
+    assert cache.lookup(_pk(1)) is None        # B went, not A
+    assert meter.count - before == 1
+    assert cache.evict(_pk(2))                 # C never served either
+    snap = cache.snapshot()
+    assert snap["audit_evictions"] == 1 and snap["evicted_unused"] == 1
+    cache.install(_pk(3), _fake_table())
+    cache.install(_pk(4), _fake_table())       # evicts A, which served
+    snap = cache.snapshot()
+    assert snap["evictions"] == 2 and snap["evicted_unused"] == 1
+    assert meter.count - before == 1
+
+
 # --------------- C: byte-aligned signed radix-256 recode ---------------
 
 
