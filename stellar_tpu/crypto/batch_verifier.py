@@ -295,14 +295,16 @@ class BatchVerifier(BatchEngine):
 
     def submit(self, items: Sequence[tuple], trace_ids=None,
                variant=None) -> Callable[[], np.ndarray]:
-        """Partitioning submit (ISSUE 16): rows whose signer already
-        has a cached A-table ride the hot radix-256 kernel variant;
-        the rest ride the unchanged cold path — which populates the
-        cache, so a signer's FIRST sight is cold and every repeat is
-        hot. The partition is decided per row at encode time from the
-        cache alone (content-keyed, deterministic — two replicas fed
-        the same traffic split identically); verdicts are bit-identical
-        either way, so the split can never change a decision. With the
+        """Partitioning submit: rows whose signer already has a cached
+        A-table ride the hot radix-256 kernel variant; the rest ride
+        the unchanged cold path — which populates the cache, so a
+        signer's FIRST sight is cold and its repeats are hot (once the
+        cache is full, from the sight after its second: see
+        :meth:`SignerTableCache.admit`). The partition is decided per
+        row at encode time from the cache alone (content-keyed,
+        deterministic — two replicas fed the same traffic split
+        identically); verdicts are bit-identical either way, so the
+        split can never change a decision. With the
         cache disabled (``VERIFY_SIGNER_TABLE_ENABLED=0`` /
         ``configure_dispatch(signer_table_enabled=False)``) every row
         rides cold and this is exactly the pre-16 engine submit."""
@@ -327,15 +329,19 @@ class BatchVerifier(BatchEngine):
                     continue
                 cold_idx.append(i)
                 cold_items.append(it)
-                if len(pk) == 32:
-                    # first sight: build + install NOW (one
-                    # incremental chain + one batched inversion,
+                if len(pk) == 32 and cache.admit(pk):
+                    # a miss the cache admits: build + install NOW
+                    # (one incremental chain + one batched inversion,
                     # about 3 ms per key on a TPU v5e host) so the
                     # next occurrence — even later in this very
                     # batch — hits; THIS row still rides cold (its
                     # verdict needs the full decompress gate the cold
-                    # kernel carries). The build sits on the call's
-                    # critical path, before any dispatch.
+                    # kernel carries). While the budget has room a
+                    # first sight builds; once it is full a key must
+                    # be seen twice, so a long tail of one-off
+                    # signers builds nothing LRU would flush unserved.
+                    # The build sits on the call's critical path,
+                    # before any dispatch.
                     with tracing.span(f"{ns}.table_build"):
                         built = signer_tables.build_signer_table(pk)
                         if built is not None:

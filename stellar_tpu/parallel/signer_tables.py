@@ -25,6 +25,15 @@ sibling this cache is keyed and byte-bounded exactly like:
 * a table evicted by the budget before it served a single lookup
   counts in ``evicted_unused``: a build whose cost bought nothing,
   the waste a long tail of one-off signers makes;
+* admission (:meth:`SignerTableCache.admit`): while the budget has
+  room a signer's table is built on its first sight; once an install
+  would evict, a key must be seen twice before it is built. A
+  full-cache first sight only records the key's fingerprint in a
+  seen-once set (insertion-ordered, bounded at the number of tables
+  the budget holds, oldest dropped first) and counts in ``deferred``;
+  its row rides cold, as a first sight always does. A long tail of
+  one-off signers then stops building tables LRU flushes unserved,
+  and stops flushing the tables that were serving;
 * :func:`SignerTableCache.evict` exists for the AUDIT path — a
   ``corrupt-device`` conviction while a cached table served the batch
   evicts that signer's entry (a poisoned resident table must never
@@ -46,10 +55,11 @@ cache membership IS the decompression proof. A pubkey that fails
 decompression is never cached (and never dispatches hot).
 
 Determinism (nondet-lint scope): content-derived keys, no clocks, no
-RNG; recency order depends only on the call sequence. All shared state
-mutates under the instance lock (lock-lint scope). This module must
-stay importable WITHOUT jax — the table builder is pure Python + numpy
-(``batch_verifier`` defers jax the same way).
+RNG; recency order and the seen-once set depend only on the call
+sequence. All shared state mutates under the instance lock (lock-lint
+scope). This module must stay importable WITHOUT jax — the table
+builder is pure Python + numpy (``batch_verifier`` defers jax the same
+way).
 """
 
 from __future__ import annotations
@@ -156,6 +166,7 @@ class SignerTableCache:
                  enabled: bool = _ENABLED_DEFAULT):
         self._lock = threading.Lock()
         self._entries: OrderedDict = OrderedDict()  # fp -> _Entry
+        self._seen_once: OrderedDict = OrderedDict()  # fp -> None
         self._max_bytes = max(0, int(max_bytes))
         self._enabled = bool(enabled)
         self._hits = 0
@@ -164,15 +175,17 @@ class SignerTableCache:
         self._evictions = 0
         self._evicted_unused = 0
         self._audit_evictions = 0
+        self._deferred = 0
 
     # ---------------- knobs ----------------
 
     def configure(self, max_bytes: Optional[int] = None,
                   enabled: Optional[bool] = None) -> None:
         """Config push (VERIFY_SIGNER_TABLE_*); None keeps current.
-        Shrinking the budget evicts immediately; disabling clears the
-        cache (a table must not outlive the decision to stop serving
-        hot — the next batch runs all-cold, verdicts unchanged)."""
+        Shrinking the budget evicts immediately and trims the seen-once
+        set to the new table count; disabling clears both (a table
+        must not outlive the decision to stop serving hot — the next
+        batch runs all-cold, verdicts unchanged)."""
         with self._lock:
             if max_bytes is not None:
                 self._max_bytes = max(0, int(max_bytes))
@@ -180,7 +193,9 @@ class SignerTableCache:
                 self._enabled = bool(enabled)
                 if not self._enabled:
                     self._entries.clear()
+                    self._seen_once.clear()
             self._evict_locked()
+            self._trim_seen_locked()
 
     @property
     def enabled(self) -> bool:
@@ -205,6 +220,31 @@ class SignerTableCache:
             self._hits += 1
         registry.counter(f"{_NS}.hits").inc()
         return hit.table
+
+    def admit(self, pk: bytes) -> bool:
+        """Build this missed signer's table now? True while an install
+        would evict nothing (first sight builds, as ever). Once the
+        budget is full, a key's first sight records its fingerprint in
+        the seen-once set, counts in ``deferred`` and returns False
+        (its row rides cold, nothing is built); its next sight while
+        still recorded leaves the set and returns True. False when no
+        table can be installed at all (disabled, or a budget below
+        one table)."""
+        fp = signer_fingerprint(pk)
+        with self._lock:
+            capacity = self._max_bytes // TABLE_BYTES
+            if not self._enabled or capacity < 1:
+                return False
+            if len(self._entries) < capacity:
+                return True
+            if fp in self._seen_once:
+                del self._seen_once[fp]
+                return True
+            self._seen_once[fp] = None
+            self._trim_seen_locked()
+            self._deferred += 1
+        registry.meter(f"{_NS}.deferred").mark()
+        return False
 
     def install(self, pk: bytes, table: np.ndarray) -> bool:
         """Retain one freshly-built table; returns True when cached.
@@ -246,6 +286,11 @@ class SignerTableCache:
                 self._evicted_unused += 1
                 registry.meter(f"{_NS}.evicted_unused").mark()
 
+    def _trim_seen_locked(self) -> None:
+        bound = self._max_bytes // TABLE_BYTES
+        while len(self._seen_once) > bound:
+            self._seen_once.popitem(last=False)
+
     # ---------------- introspection ----------------
 
     def snapshot(self) -> dict:
@@ -263,6 +308,8 @@ class SignerTableCache:
                 "evictions": self._evictions,
                 "evicted_unused": self._evicted_unused,
                 "audit_evictions": self._audit_evictions,
+                "deferred": self._deferred,
+                "seen_once": len(self._seen_once),
             }
 
     def _reset_for_testing(self) -> None:
@@ -273,6 +320,7 @@ class SignerTableCache:
         policy)."""
         with self._lock:
             self._entries.clear()
+            self._seen_once.clear()
             self._max_bytes = max(0, int(DEFAULT_CACHE_BYTES))
             self._enabled = bool(_ENABLED_DEFAULT)
             self._hits = 0
@@ -281,6 +329,7 @@ class SignerTableCache:
             self._evictions = 0
             self._evicted_unused = 0
             self._audit_evictions = 0
+            self._deferred = 0
 
 
 # process-wide cache (one node per process — signer hotness is a

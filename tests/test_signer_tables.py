@@ -201,6 +201,102 @@ def test_evicted_unused_counts_tables_that_never_served():
     assert meter.count - before == 1
 
 
+def _fill(cache, n):
+    for i in range(n):
+        assert cache.admit(_pk(i))              # room: first sight builds
+        assert cache.install(_pk(i), _fake_table())
+
+
+def test_admit_builds_first_sight_while_the_budget_has_room():
+    cache = st.SignerTableCache(max_bytes=3 * st.TABLE_BYTES)
+    _fill(cache, 3)
+    snap = cache.snapshot()
+    assert snap["entries"] == 3 and snap["installs"] == 3
+    assert snap["deferred"] == 0 and snap["seen_once"] == 0
+    assert snap["evictions"] == 0
+
+
+def test_full_cache_defers_first_sight_admits_second_then_hits():
+    from stellar_tpu.utils.metrics import registry
+    meter = registry.meter("crypto.verify.signer_table.deferred")
+    before = meter.count
+    cache = st.SignerTableCache(max_bytes=2 * st.TABLE_BYTES)
+    _fill(cache, 2)
+    assert cache.lookup(_pk(9)) is None
+    assert not cache.admit(_pk(9))             # first sight: nothing built
+    snap = cache.snapshot()
+    assert snap["deferred"] == 1 and snap["seen_once"] == 1
+    assert snap["entries"] == 2 and snap["evictions"] == 0
+    assert meter.count - before == 1
+    assert cache.lookup(_pk(9)) is None
+    assert cache.admit(_pk(9))                 # second sight: build
+    assert cache.snapshot()["seen_once"] == 0
+    cache.install(_pk(9), _fake_table())
+    assert cache.lookup(_pk(9)) is not None    # third sight: hot
+    snap = cache.snapshot()
+    assert snap["deferred"] == 1 and snap["evictions"] == 1
+    assert snap["entries"] == 2 and cache.lookup(_pk(0)) is None
+    assert meter.count - before == 1
+
+
+def test_seen_once_set_is_bounded_at_the_table_count_oldest_first():
+    cache = st.SignerTableCache(max_bytes=2 * st.TABLE_BYTES)
+    _fill(cache, 2)
+    for i in (10, 11, 12):
+        assert not cache.admit(_pk(i))
+    snap = cache.snapshot()
+    assert snap["seen_once"] == 2 and snap["deferred"] == 3
+    assert not cache.admit(_pk(10))            # 10, the oldest, was dropped
+    assert cache.admit(_pk(12))                # 12 is still recorded
+    assert not cache.admit(_pk(11))            # 10's re-entry dropped 11
+    snap = cache.snapshot()
+    assert snap["seen_once"] == 2 and snap["deferred"] == 5
+
+
+def test_configure_shrink_trims_seen_once_and_disable_clears_it():
+    cache = st.SignerTableCache(max_bytes=4 * st.TABLE_BYTES)
+    _fill(cache, 4)
+    for i in (10, 11, 12, 13):
+        assert not cache.admit(_pk(i))
+    assert cache.snapshot()["seen_once"] == 4
+    cache.configure(max_bytes=2 * st.TABLE_BYTES)
+    snap = cache.snapshot()
+    assert snap["entries"] == 2 and snap["seen_once"] == 2
+    assert cache.admit(_pk(13))                # the newest two survive
+    assert not cache.admit(_pk(11))            # the oldest went
+    cache.configure(enabled=False)
+    snap = cache.snapshot()
+    assert snap["entries"] == 0 and snap["seen_once"] == 0
+    deferred = snap["deferred"]
+    assert not cache.admit(_pk(12))            # nothing can be installed
+    assert cache.snapshot()["deferred"] == deferred
+    cache.configure(enabled=True)
+    assert cache.admit(_pk(12))                # empty again: room
+    assert not st.SignerTableCache(
+        max_bytes=st.TABLE_BYTES - 1).admit(_pk(0))
+
+
+def test_two_caches_fed_the_same_keys_end_identical():
+    """Admission is content-keyed and ordered by the call sequence
+    alone: two replicas partition the same traffic identically."""
+    rng = np.random.default_rng(0xAD17)
+    seq = [int(x) for x in rng.zipf(1.3, size=600) % 200]
+    caches = [st.SignerTableCache(max_bytes=8 * st.TABLE_BYTES)
+              for _ in range(2)]
+    table = _fake_table()
+    for cache in caches:
+        for k in seq:
+            if cache.lookup(_pk(k)) is None and cache.admit(_pk(k)):
+                cache.install(_pk(k), table)
+    a, b = caches
+    assert list(a._entries) == list(b._entries)
+    assert list(a._seen_once) == list(b._seen_once)
+    assert a.snapshot() == b.snapshot()
+    snap = a.snapshot()
+    assert snap["deferred"] > 0 and snap["evictions"] > 0
+    assert snap["hits"] > 0 and snap["seen_once"] > 0
+
+
 # --------------- C: byte-aligned signed radix-256 recode ---------------
 
 
@@ -281,6 +377,27 @@ def test_uncacheable_rows_always_ride_cold(fresh_dispatch):
     snap = bv.dispatch_health()["signer_tables"]
     assert snap["entries"] == 1 and snap["installs"] == 1
     assert snap["hits"] == 1                   # only the repeat of pk
+
+
+def test_failed_build_under_a_full_cache_installs_nothing(fresh_dispatch):
+    """An undecompressable key is admitted on its second sight under a
+    full budget, its build fails, and the resident table stays: the
+    cache neither grows nor evicts."""
+    bv._enter_host_only("test: partition without kernels")
+    st.signer_table_cache.configure(max_bytes=st.TABLE_BYTES)
+    v = BatchVerifier(bucket_sizes=(16,))
+    pk, good, _bad = _hot_pool()
+    y = 2
+    while ref.point_decompress(int(y).to_bytes(32, "little")) is not None:
+        y += 1
+    undec = (int(y).to_bytes(32, "little"), b"m", bytes(64))
+    rows = [good, undec, undec, good]
+    assert list(v.verify_batch(rows)) == [True, False, False, True]
+    snap = bv.dispatch_health()["signer_tables"]
+    assert snap["installs"] == 1 and snap["entries"] == 1
+    assert snap["deferred"] == 1 and snap["seen_once"] == 0
+    assert snap["evictions"] == 0 and snap["hits"] == 1
+    assert st.signer_table_cache.lookup(pk) is not None
 
 
 def test_audit_conviction_evicts_served_tables(fresh_dispatch):
