@@ -198,7 +198,6 @@ def _transfer_totals_delta(before: dict, after: dict) -> dict:
     the same record as the span split)."""
     keys = ("round_trips", "bytes_h2d", "bytes_d2h", "device_puts",
             "fetches", "redundant_constant_bytes", "redundant_uploads",
-            "resident_hits", "resident_bytes",
             "unfingerprinted_uploads", "unfingerprinted_bytes")
     return {k: after.get(k, 0) - before.get(k, 0) for k in keys}
 

@@ -89,10 +89,6 @@ SCOPE = [
     "stellar_tpu/parallel/hostbuf.py",
     "stellar_tpu/parallel/batch_engine.py",
     "stellar_tpu/parallel/device_health.py",
-    # the device-resident constant cache (ISSUE 12): its LRU mutates
-    # from every dispatching thread (trickle leaders, service
-    # dispatcher, chaos tests) through the engine's placement path
-    "stellar_tpu/parallel/residency.py",
     # the per-pubkey signer-table cache (ISSUE 16): its LRU mutates
     # from every submitting thread at partition time and from the
     # engine's audit-conviction eviction hook
@@ -196,13 +192,6 @@ ALLOWLIST = Allowlist({
             "config push at startup, torn reads impossible under the "
             "GIL; a racing resolve sees either the old or the new "
             "rate, both of which sample deterministically.",
-        "unlocked-global:configure_dispatch.DONATE_BUFFERS":
-            "single atomic store of an immutable str (no "
-            "read-modify-write): same argument as DEADLINE_MS — "
-            "config push at startup; a racing dispatch reads either "
-            "the old or the new policy, and both produce "
-            "bit-identical results (donation changes buffer "
-            "lifetimes, never rows).",
     },
 })
 

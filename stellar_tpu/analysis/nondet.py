@@ -105,11 +105,6 @@ HOST_ORACLE_FILES = [
     # content-derived, so two runs over the same bytes always agree
     "stellar_tpu/utils/transfer_ledger.py",
     "tools/perf_sentinel.py",
-    # the device-resident constant cache (ISSUE 12) decides which
-    # operand uploads are skipped: keys must be content-derived and
-    # eviction clock/RNG-free, or replicas could pin different buffers
-    # (a latency divergence only — but the discipline is free to keep)
-    "stellar_tpu/parallel/residency.py",
     # the per-pubkey signer-table cache (ISSUE 16) decides which rows
     # dispatch HOT vs cold: keys must be content-derived and eviction
     # clock/RNG-free — verdicts are path-independent (pinned by the

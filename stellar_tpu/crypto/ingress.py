@@ -15,9 +15,8 @@ items in place: message bytes enter the service queues as
 :class:`memoryview` slices of the lease (``pk``/``sig`` are 96 fixed
 hashable bytes), and the lease is refcounted per frame — the buffer
 is reused only after every ticket decoded from it reached a terminal
-and its response left on the wire, so the donated-buffer dispatch
-path reads wire bytes that were copied exactly once (kernel →
-lease).
+and its response left on the wire, so the dispatch path reads wire
+bytes that were copied exactly once (kernel → lease).
 
 **Traces start on the wire.** The reader allocates a contiguous
 trace block (``verify_service._alloc_trace_block``) the moment a

@@ -221,10 +221,6 @@ class Application:
                 changed("VERIFY_DEVICE_FAILURE_THRESHOLD") or \
                 changed("VERIFY_DEVICE_BACKOFF_MIN_S") or \
                 changed("VERIFY_DEVICE_BACKOFF_MAX_S") or \
-                changed("VERIFY_DONATE_BUFFERS") or \
-                changed("VERIFY_RESIDENT_CACHE_BYTES") or \
-                changed("VERIFY_RESIDENT_MAX_ITEM_BYTES") or \
-                changed("VERIFY_RESIDENT_CONSTANTS") or \
                 changed("VERIFY_SIGNER_TABLE_BYTES") or \
                 changed("VERIFY_SIGNER_TABLE_ENABLED"):
             from stellar_tpu.crypto import batch_verifier
@@ -239,11 +235,6 @@ class Application:
                     config.VERIFY_DEVICE_FAILURE_THRESHOLD),
                 device_backoff_min_s=config.VERIFY_DEVICE_BACKOFF_MIN_S,
                 device_backoff_max_s=config.VERIFY_DEVICE_BACKOFF_MAX_S,
-                donate_buffers=config.VERIFY_DONATE_BUFFERS,
-                resident_cache_bytes=config.VERIFY_RESIDENT_CACHE_BYTES,
-                resident_max_item_bytes=(
-                    config.VERIFY_RESIDENT_MAX_ITEM_BYTES),
-                resident_enabled=config.VERIFY_RESIDENT_CONSTANTS,
                 signer_table_bytes=config.VERIFY_SIGNER_TABLE_BYTES,
                 signer_table_enabled=(
                     config.VERIFY_SIGNER_TABLE_ENABLED))

@@ -175,21 +175,6 @@ class Config:
     # mismatch quarantines the device and flips verify host-only — a
     # corrupting chip must never decide signature validity.
     VERIFY_AUDIT_RATE: float = 0.02
-    # dispatch-floor levers (ISSUE 12, docs/benchmarks.md "Dispatch
-    # floor"): donated input buffers for one-off operand uploads —
-    # "auto" donates only on a real accelerator (jax-CPU ignores
-    # donation), "1"/"0" force it
-    VERIFY_DONATE_BUFFERS: str = "auto"
-    # device-resident constant tables: byte budget of committed device
-    # buffers retained per process (keyed by content fingerprint, LRU)
-    # so identical operand bytes upload once per device per process
-    VERIFY_RESIDENT_CACHE_BYTES: int = 128 << 20
-    # per-operand size cap for residency (the SHA-256 fingerprint runs
-    # on the dispatch hot path; oversize operands ride donation)
-    VERIFY_RESIDENT_MAX_ITEM_BYTES: int = 1 << 20
-    # master switch for the resident cache (disable to re-measure the
-    # raw re-upload floor the transfer ledger indicts)
-    VERIFY_RESIDENT_CONSTANTS: bool = True
     # hot-signer per-pubkey A-table cache (ISSUE 16,
     # stellar_tpu/parallel/signer_tables.py): byte budget of host
     # retained 128-entry affine tables (15 KiB/signer, LRU by content

@@ -29,31 +29,18 @@ interface**:
   spans and counters), generic over the plugin's array tuple and
   result rows.
 
-**Dispatch floor (ISSUE 12).** The ledger/profiler instrumentation of
-PRs 8+10 measured ``redundancy_frac`` 1.0 and ``overlap_frac`` 0.0 on
-the old dispatch loop; this engine spends that measurement with four
-coordinated levers, each provable from the same gated telemetry:
+**Dispatch floor.** Every dispatched operand takes one path: a
+``device_put`` to its destination, recorded once by the transfer
+ledger and by the engine's byte tally; the engine keeps no device
+buffer beyond the call that uses it and compiles one executable per
+(variant, dispatch shape). Two levers bound the cost of that path:
 
-* **device-resident constant tables**
-  (:mod:`stellar_tpu.parallel.residency`): operand uploads are keyed
-  by content fingerprint and retained on device — identical bytes
-  upload once per placement per process; re-dispatches are served
-  from the resident committed array (``resident_hits`` in the
-  ledger), so ``redundant_constant_bytes`` sits at ~0 after warm-up
-  and is sentinel-pinned there;
-* **donated input buffers**: one-off operands the cache does NOT
-  retain dispatch through ``donate_argnums`` executables
-  (``VERIFY_DONATE_BUFFERS``, auto = real accelerators only), so
-  their device buffers are released without a defensive copy — never
-  for resident buffers (a donated buffer is consumed, a resident one
-  must survive for the next hit);
 * **coalesced per-mesh dispatch**: a fully healthy mesh serving a
   full bucket ships ONE sharded h2d upload whose per-device shards
   feed the SAME per-device sub-chunk executables — n_devices×n_arrays
-  ``device_put`` round trips collapse to n_arrays (or zero, on a
-  resident hit) while per-device fault attribution, breakers,
-  degraded re-shard, probation grants and the sampled audit keep
-  their existing shape;
+  ``device_put`` round trips collapse to n_arrays while per-device
+  fault attribution, breakers, degraded re-shard, probation grants
+  and the sampled audit keep their existing shape;
 * **async pipelined submit**: batches wider than the top bucket
   encode/pad chunk ``k+1`` while chunk ``k`` is in flight and fetch
   only verdict bits — host prep hides behind device work
@@ -108,7 +95,7 @@ from typing import Callable, Optional, Sequence, Tuple
 import numpy as np
 
 from stellar_tpu.crypto import audit as audit_mod
-from stellar_tpu.parallel import device_health, residency, signer_tables
+from stellar_tpu.parallel import device_health, signer_tables
 from stellar_tpu.utils import faults, resilience, tracing
 from stellar_tpu.utils.metrics import registry
 from stellar_tpu.utils.timeline import pipeline_timeline
@@ -143,15 +130,6 @@ DISPATCH_RETRIES = int(os.environ.get("VERIFY_DISPATCH_RETRIES", "1"))
 # disables). The sample is derived from the batch CONTENT
 # (crypto/audit.py) so consensus replicas audit identical rows.
 AUDIT_RATE = float(os.environ.get("VERIFY_AUDIT_RATE", "0.02"))
-# Donated input buffers (ISSUE 12): operand uploads the resident cache
-# does NOT retain (one-off payloads, oversize arrays) are dispatched
-# through a donate_argnums executable so the device may reuse their
-# buffers instead of paying a defensive copy. "auto" donates only on a
-# real accelerator (jax-CPU ignores donation and would just warn);
-# "1"/"0" force it for tests. A donated dispatch never retries — the
-# operands are gone after the first attempt — so failures go straight
-# to attribution + host fallback.
-DONATE_BUFFERS = os.environ.get("VERIFY_DONATE_BUFFERS", "auto")
 
 # The production jit bucket ladder (the verify workload's
 # default_verifier). Also the shape set the static overflow prover must
@@ -285,10 +263,6 @@ def configure_dispatch(deadline_ms: Optional[float] = None,
                        device_failure_threshold: Optional[int] = None,
                        device_backoff_min_s: Optional[float] = None,
                        device_backoff_max_s: Optional[float] = None,
-                       donate_buffers: Optional[str] = None,
-                       resident_cache_bytes: Optional[int] = None,
-                       resident_max_item_bytes: Optional[int] = None,
-                       resident_enabled: Optional[bool] = None,
                        signer_table_bytes: Optional[int] = None,
                        signer_table_enabled: Optional[bool] = None
                        ) -> None:
@@ -296,23 +270,17 @@ def configure_dispatch(deadline_ms: Optional[float] = None,
     current value. ``deadline_ms <= 0`` disables the resolve watchdog;
     ``audit_rate <= 0`` disables the result-integrity audit; the
     ``device_*`` knobs shape the per-device quarantine breakers; the
-    ``donate_buffers`` / ``resident_*`` knobs shape the dispatch-floor
-    levers (ISSUE 12: donated one-off operands, device-resident
-    constant tables); the ``signer_table_*`` knobs shape the hot-signer
-    per-pubkey A-table cache (ISSUE 16,
-    ``stellar_tpu.parallel.signer_tables``). The knobs govern EVERY
-    workload on the substrate
-    (verify and hash dispatches share the devices whose health they
-    model — and the resident buffers living on its chips)."""
-    global DEADLINE_MS, DISPATCH_RETRIES, AUDIT_RATE, DONATE_BUFFERS
+    ``signer_table_*`` knobs shape the hot-signer per-pubkey A-table
+    cache (``stellar_tpu.parallel.signer_tables``). The knobs
+    govern EVERY workload on the substrate (verify and hash dispatches
+    share the devices whose health they model)."""
+    global DEADLINE_MS, DISPATCH_RETRIES, AUDIT_RATE
     if deadline_ms is not None:
         DEADLINE_MS = float(deadline_ms)
     if dispatch_retries is not None:
         DISPATCH_RETRIES = max(0, int(dispatch_retries))
     if audit_rate is not None:
         AUDIT_RATE = float(audit_rate)
-    if donate_buffers is not None:
-        DONATE_BUFFERS = str(donate_buffers)
     _breaker.configure(failure_threshold=failure_threshold,
                        backoff_min_s=backoff_min_s,
                        backoff_max_s=backoff_max_s)
@@ -320,49 +288,9 @@ def configure_dispatch(deadline_ms: Optional[float] = None,
         failure_threshold=device_failure_threshold,
         backoff_min_s=device_backoff_min_s,
         backoff_max_s=device_backoff_max_s)
-    residency.resident_cache.configure(
-        max_bytes=resident_cache_bytes,
-        max_item_bytes=resident_max_item_bytes,
-        enabled=resident_enabled)
     signer_tables.signer_table_cache.configure(
         max_bytes=signer_table_bytes,
         enabled=signer_table_enabled)
-
-
-_donate_warn_lock = threading.Lock()
-_donate_warn_filtered = False
-
-
-def _filter_donation_warning_once() -> None:
-    """Install (once per process) the ignore-filter for XLA's
-    'donated buffers were not usable' nag: our kernels' outputs never
-    alias their inputs (verdict bits / digest words vs byte
-    operands), so every donating compile would warn — the buffers are
-    still released early. Installed lazily at the FIRST donating
-    build, so a process that never donates keeps its warning state
-    untouched, and exactly one filter entry ever lands in the global
-    list."""
-    global _donate_warn_filtered
-    import warnings
-    with _donate_warn_lock:
-        if _donate_warn_filtered:
-            return
-        _donate_warn_filtered = True
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-
-
-def _donation_active() -> bool:
-    """May dispatches donate their (non-resident) operand buffers?
-    "auto" donates only when a REAL accelerator answered the probe:
-    jax-CPU ignores donation entirely, so forcing it there would buy
-    nothing and add a second executable per shape to the compile
-    budget the chaos suites are pinned against."""
-    if DONATE_BUFFERS == "1":
-        return True
-    if DONATE_BUFFERS == "auto":
-        return _device_state not in (None, "cpu", "dead")
-    return False
 
 
 # ---------------- host-only mode (result-integrity posture) ----------------
@@ -506,9 +434,7 @@ def dispatch_health() -> dict:
         "watchdog": resilience.watchdog_stats(),
         "flight_recorder": tracing.flight_recorder.stats(),
         "transfer": transfer_ledger.totals(),
-        "resident": residency.resident_cache.snapshot(),
         "signer_tables": signer_tables.signer_table_cache.snapshot(),
-        "donate_buffers": DONATE_BUFFERS,
         "service": service_health_snapshot(),
         "fleet": fleet_health_snapshot(),
     }
@@ -628,7 +554,7 @@ class Workload:
     #: variant plugin (a different kernel over the same result rows,
     #: submitted via ``submit(..., variant=...)`` — e.g. the hot-signer
     #: cached-table kernel, ISSUE 16) must set a unique name: its jit
-    #: wrappers are cached under ``(variant_name, donate)`` so
+    #: wrappers are cached under ``variant_name`` so
     #: ``sorted(engine._kernels)`` stays exactly the primary shape set
     #: the compile-reuse invariant pins.
     variant_name: Optional[str] = None
@@ -721,16 +647,11 @@ class BatchEngine:
         # on a mesh): written from any thread that dispatches (trickle
         # leaders, chaos tests, the close path) — guarded, the wrapper
         # itself is built outside the lock (cheap; the compile happens
-        # lazily at first call). Donating variants live in a separate
-        # dict so `sorted(self._kernels)` stays the shape set the
-        # compile-reuse invariant pins, and a jax-CPU process (where
-        # donation is off) never builds — or compiles — the second
-        # executable per shape.
+        # lazily at first call).
         self._kernels = {}
-        self._kernels_donate = {}
-        # variant-kernel caches keyed (variant_name, donate) -> {shape:
-        # jit wrapper}: kernel VARIANTS (ISSUE 16's hot-signer path)
-        # never leak into the two primary dicts above, so the pinned
+        # variant-kernel caches keyed variant_name -> {shape: jit
+        # wrapper}: kernel VARIANTS (the hot-signer path) never
+        # leak into the primary dict above, so the pinned
         # `sorted(self._kernels)` shape sets survive variant traffic
         self._kernels_variants = {}
         self._kernels_lock = threading.Lock()
@@ -742,14 +663,10 @@ class BatchEngine:
         self.deadline_misses = 0
         self.retries = 0
         self.audit_mismatches = 0
-        # dispatch-floor lever attribution (ISSUE 12): how many
-        # buckets rode the single coalesced per-mesh upload, how many
-        # kernel calls donated their operands, and how many operand
-        # uploads the resident constant cache absorbed — the engine's
-        # own view of the levers, next to the ledger's byte view
+        # how many buckets rode the single coalesced per-mesh upload
+        # — the engine's own view of that lever, next to the ledger's
+        # byte view
         self.coalesced_dispatches = 0
-        self.donated_dispatches = 0
-        self.resident_hits = 0
         # engine-side byte accounting, derived INDEPENDENTLY from the
         # dispatch shapes (prod(shape) * itemsize at the placement
         # sites) — the reconciliation oracle the transfer ledger's
@@ -771,31 +688,25 @@ class BatchEngine:
 
     # ---------------- device dispatch ----------------
 
-    def _kernel_for(self, n: int, donate: bool = False,
-                    n_args: Optional[int] = None, *, plugin=None):
+    def _kernel_for(self, n: int, *, plugin=None):
         # keyword-only `plugin` keeps the positional signature stable
         # (harnesses call `_kernel_for(shape)` directly to pre-warm)
         if plugin is None or plugin is self._plugin:
             plugin = self._plugin
-            cache = self._kernels_donate if donate else self._kernels
+            cache = self._kernels
         else:
             with self._kernels_lock:
                 cache = self._kernels_variants.setdefault(
-                    (plugin.variant_name, donate), {})
+                    plugin.variant_name, {})
         with self._kernels_lock:
             kernel = cache.get(n)
         if kernel is None:
             import jax
-            if donate:
-                _filter_donation_warning_once()
-                built = jax.jit(plugin.kernel_fn(),
-                                donate_argnums=tuple(range(n_args)))
-            else:
-                # one plain jit wrapper per dispatch shape; on the
-                # mesh path placement follows the committed inputs,
-                # so the SAME wrapper serves every device (jax caches
-                # one executable per (shape, device) underneath)
-                built = jax.jit(plugin.kernel_fn())
+            # one plain jit wrapper per dispatch shape; on the mesh
+            # path placement follows the committed inputs, so the SAME
+            # wrapper serves every device (jax caches one executable
+            # per (shape, device) underneath)
+            built = jax.jit(plugin.kernel_fn())
             with self._kernels_lock:
                 # setdefault: a racing builder's wrapper wins once —
                 # both wrappers trace identically, so the loser is
@@ -810,24 +721,14 @@ class BatchEngine:
         return self._buckets[-1]
 
     def _dispatch_one(self, arrays: tuple, bsize: int,
-                      dev_idx: Optional[int],
-                      donate: bool = False, *, plugin=None):
+                      dev_idx: Optional[int], *, plugin=None):
         """One kernel call (whole padded bucket, or one per-device
         sub-chunk): inject-point + retry + failure attribution. Returns
-        the in-flight device array, or None (host fallback).
-        ``donate=True`` dispatches through the donate_argnums variant
-        — and therefore never retries (the operand buffers are
-        consumed by the first attempt)."""
-        attempts = 1 if donate else 1 + DISPATCH_RETRIES
+        the in-flight device array, or None (host fallback)."""
+        attempts = 1 + DISPATCH_RETRIES
         for attempt in range(attempts):
             try:
                 faults.inject(faults.DISPATCH, device=dev_idx)
-                if donate:
-                    with self._stats_lock:
-                        self.donated_dispatches += 1
-                    return self._kernel_for(
-                        bsize, donate=True,
-                        n_args=len(arrays), plugin=plugin)(*arrays)
                 return self._kernel_for(bsize, plugin=plugin)(*arrays)
             except Exception as e:
                 if attempt + 1 < attempts:
@@ -839,90 +740,48 @@ class BatchEngine:
                     _note_device_failure("dispatch", e, dev_idx)
         return None
 
-    def _ship_accounting(self, arrays) -> int:
+    def _ship_accounting(self, a) -> int:
         """Engine-side independent byte count of one upload (shape ×
         itemsize — NOT the ledger's ``nbytes`` read, so the two tallies
         reconcile only when both paths saw the same arrays)."""
-        total = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                    for a in arrays)
+        total = int(np.prod(a.shape)) * a.dtype.itemsize
         with self._stats_lock:
             self.shipped_bytes += total
         return total
 
-    def _place_operands(self, tok, arrays: tuple, dest, pkey,
-                        dev_idx: Optional[int] = None):
-        """Commit one operand tuple to ``dest`` (a device, a
-        per-mesh Sharding, or None = the default device) through the
-        device-resident constant cache: an operand whose exact bytes
-        are already resident at this placement is served from the
-        cached committed array — no upload, the ledger records a
-        resident hit — and a fresh upload is retained for the next
-        identical dispatch. Returns ``(placed_tuple, donatable)``:
-        donatable only when EVERY operand was freshly uploaded and
-        none was retained (a donated buffer is consumed by the kernel
-        and must never be a cache entry someone will reuse)."""
+    def _place_operands(self, tok, arrays: tuple, dest,
+                        dev_idx: Optional[int] = None) -> tuple:
+        """Commit one operand tuple to ``dest`` (a device, a per-mesh
+        Sharding, or None = the default device) and return the placed
+        tuple. Each operand is one ``device_put``, recorded by the
+        transfer ledger and by the engine's own shape-derived tally —
+        the reconciliation oracle of ``tools/transfer_selfcheck.py``."""
         import jax
-        cache = residency.resident_cache
         placed = []
-        donatable = _donation_active()
-        fresh = hits = 0
+        shipped = 0
         with tracing.span(f"{self._span_ns}.place") as sp:
             for a in arrays:
-                fp = residency.fingerprint(a)
-                hit = cache.get(fp, a, pkey)
-                if hit is not None:
-                    transfer_ledger.record_resident_hit(tok, a,
-                                                        device=dev_idx)
-                    with self._stats_lock:
-                        self.resident_hits += 1
-                    placed.append(hit)
-                    donatable = False
-                    hits += 1
-                    continue
-                put = jax.device_put(a, dest) if dest is not None \
-                    else jax.device_put(a)
-                # transfer ledger: the device_put IS the h2d upload; the
-                # engine's own shape-derived tally is the reconciliation
-                # oracle (tools/transfer_selfcheck.py). The precomputed
-                # fingerprint is forwarded only when residency actually
-                # computed one — an operand over the RESIDENCY size cap
-                # must still be fingerprinted under the ledger's OWN cap
-                # (TRANSFER_LEDGER_FP_MAX_BYTES), or the redundancy
-                # detector would silently lose exactly the mid-size
-                # constants between the two knobs
-                if fp is not None:
-                    transfer_ledger.record_h2d(tok, a, device=dev_idx,
-                                               fp=fp)
-                else:
-                    transfer_ledger.record_h2d(tok, a, device=dev_idx)
-                fresh += self._ship_accounting((a,))
-                if cache.put(fp, a, pkey, put):
-                    donatable = False
-                placed.append(put)
-            sp.set_attrs(h2d_bytes=fresh, resident_hits=hits)
-        return tuple(placed), donatable
+                placed.append(jax.device_put(a, dest))
+                transfer_ledger.record_h2d(tok, a, device=dev_idx)
+                shipped += self._ship_accounting(a)
+            sp.set_attrs(h2d_bytes=shipped)
+        return tuple(placed)
 
     def _coalesced_upload(self, arrays: tuple, tok):
         """ONE sharded h2d upload of the whole padded bucket — the
         coalesced per-mesh transfer replacing n_devices separate
         ``device_put`` round trips. Each operand is committed once
-        under a batch-axis NamedSharding (through the resident cache:
-        a bucket whose exact bytes already shipped is served from the
-        resident sharded array, zero new transfer); its per-device
-        shards then feed the SAME per-device sub-chunk executables the
-        legacy path compiles, so fault attribution and the
-        compile-reuse invariant are untouched.
+        under a batch-axis NamedSharding; its per-device shards then
+        feed the SAME per-device sub-chunk executables the legacy path
+        compiles, so fault attribution and the compile-reuse invariant
+        are untouched.
 
-        Returns ``(per_device_operands, donatable)`` —
-        ``{dev_idx: operand_tuple}`` of committed shard arrays — or
-        ``None`` when the upload failed (the caller falls back to the
-        attributable per-device upload path, which re-encounters and
-        properly accounts the failure)."""
+        Returns ``{dev_idx: operand_tuple}`` of committed shard
+        arrays, or ``None`` when the upload failed (the caller falls
+        back to the attributable per-device upload path, which
+        re-encounters and properly accounts the failure)."""
         from jax.sharding import NamedSharding, PartitionSpec
         n_dev = len(self._devices)
-        pkey = ("mesh",) + tuple(
-            int(getattr(d, "id", i))
-            for i, d in enumerate(self._devices))
         try:
             # the upload carries every device's shard: a per-device
             # transfer fault (stall-transfer:<idx>, fail-device) armed
@@ -931,8 +790,8 @@ class BatchEngine:
                 faults.inject(faults.TRANSFER, device=di)
             sharding = NamedSharding(
                 self._mesh, PartitionSpec(self._mesh.axis_names[0]))
-            placed, donatable = self._place_operands(
-                tok, arrays, dest=sharding, pkey=pkey, dev_idx=None)
+            placed = self._place_operands(
+                tok, arrays, dest=sharding, dev_idx=None)
         except Exception as e:
             registry.counter(
                 "crypto.verify.dispatch.coalesce_fallback").inc()
@@ -949,8 +808,7 @@ class BatchEngine:
                 by_dev[di].append(shard_by_device[dev])
         with self._stats_lock:
             self.coalesced_dispatches += 1
-        return ({di: tuple(ops) for di, ops in by_dev.items()},
-                donatable)
+        return {di: tuple(ops) for di, ops in by_dev.items()}
 
     def _dispatch_parts(self, arrays: tuple, b: int, chunk: int,
                         tok=None, traces=None, ptok=None,
@@ -1001,15 +859,14 @@ class BatchEngine:
                 f"{self._span_ns}.reshard", **reshard_attrs)
         parts = []
         if n_parts == n_dev and assignment == list(range(n_dev)):
-            coalesced = self._coalesced_upload(arrays, tok)
-            if coalesced is not None:
-                per_device, donatable = coalesced
+            per_device = self._coalesced_upload(arrays, tok)
+            if per_device is not None:
                 for j, di in enumerate(assignment):
                     lo = j * sub
                     hi = min(lo + sub, chunk)
                     arr = self._dispatch_one(
                         per_device[di], bsize=sub, dev_idx=di,
-                        donate=donatable, plugin=plugin)
+                        plugin=plugin)
                     if arr is not None:
                         # pipeline timeline: a COMMITTED kernel call
                         # opens this device's busy interval (ISSUE 10)
@@ -1030,20 +887,14 @@ class BatchEngine:
             subs = tuple(x[lo:lo + sub] for x in arrays)
             try:
                 faults.inject(faults.TRANSFER, device=di)
-                # placement key is the PHYSICAL device id (same
-                # contract as the coalesced pkey): two engines over
-                # different meshes share the process-wide cache, and
-                # a mesh-index key would alias different chips
-                placed, donatable = self._place_operands(
-                    tok, subs, dest=self._devices[di],
-                    pkey=("dev", getattr(self._devices[di], "id", di)),
-                    dev_idx=di)
+                placed = self._place_operands(
+                    tok, subs, dest=self._devices[di], dev_idx=di)
             except Exception as e:
                 _note_device_failure("transfer", e, di)
                 parts.append([lo, hi, di, None])
                 continue
             arr = self._dispatch_one(placed, bsize=sub, dev_idx=di,
-                                     donate=donatable, plugin=plugin)
+                                     plugin=plugin)
             if arr is not None:
                 # pipeline timeline: a COMMITTED kernel call opens
                 # this device's busy interval (ISSUE 10)
@@ -1122,16 +973,12 @@ class BatchEngine:
                 with tracing.span(f"{self._span_ns}.dispatch",
                                   **_span_attrs()):
                     # whole-bucket operands commit to the default
-                    # device (through the resident cache — identical
-                    # re-dispatched content uploads once per process)
-                    # before the kernel call
+                    # device before the kernel call
                     try:
                         faults.inject(faults.TRANSFER, device=None)
-                        placed, donatable = self._place_operands(
-                            tok, arrays, dest=None, pkey="default",
-                            dev_idx=None)
+                        placed = self._place_operands(
+                            tok, arrays, dest=None, dev_idx=None)
                         arr = self._dispatch_one(placed, b, None,
-                                                 donate=donatable,
                                                  plugin=plugin)
                     except Exception as e:
                         _note_device_failure("transfer", e, None)
@@ -1628,7 +1475,6 @@ def _reset_dispatch_state_for_testing() -> None:
     device_health.get()._reset_for_testing()
     transfer_ledger._reset_for_testing()
     pipeline_timeline._reset_for_testing()
-    residency.resident_cache._reset_for_testing()
     signer_tables.signer_table_cache._reset_for_testing()
 
 

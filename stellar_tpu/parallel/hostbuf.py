@@ -5,8 +5,7 @@ into a buffer leased from this pool, decodes items in place
 (messages stay :class:`memoryview` slices of the lease — see
 ``wire.decode_submit``), and hands those views straight into the
 verify service's queues: one kernel→userspace copy per frame, zero
-intermediate copies between the wire and the donated-buffer dispatch
-path (``batch_engine.configure_dispatch(DONATE_BUFFERS=...)`` — the
+intermediate copies between the wire and the dispatch path (the
 engine packs device operands from whatever host bytes it is given,
 so keeping the wire bytes stable and view-shared is what makes the
 hand-off copy-free).

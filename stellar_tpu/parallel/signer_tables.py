@@ -12,8 +12,7 @@ operand: repeat signers skip the in-kernel build entirely AND run
 byte-aligned radix-256 windows the live build could never afford
 (docs/kernel_design.md §5 carries the amortization math).
 
-Shape discipline mirrors :mod:`stellar_tpu.parallel.residency` — the
-sibling this cache is keyed and byte-bounded exactly like:
+Shape discipline:
 
 * keys are CONTENT fingerprints (SHA-256 of the pubkey encoding, no
   salts, no clocks — two replicas always cache the same signers given
@@ -41,11 +40,11 @@ sibling this cache is keyed and byte-bounded exactly like:
   pubkey bytes, which the oracle re-checks row by row).
 
 The cached value is host numpy (``(ENTRIES, 3, 20) int16`` canonical
-limbs). Device residency comes for free one layer down: the assembled
-per-batch table operand rides the engine's ``_place_operands`` →
-:mod:`residency` path, so a steady-state re-dispatch of the same hot
-batch ships ZERO redundant h2d bytes and counts ``resident_hits``
-(transfer-ledger reconciled — the acceptance gate).
+limbs). Nothing keeps a table on the device: the assembled per-batch
+table stack is one operand of the hot dispatch and is uploaded by the
+engine's ``_place_operands`` with every call that uses it (about
+31 MB for a 2,048-row bucket, far over the transfer ledger's
+fingerprint cap, so the ledger counts it bytes-only).
 
 Correctness: an entry is installed only after ``point_decompress``
 succeeded and the table rows were derived from the decompressed point
@@ -93,7 +92,7 @@ _LIMB_MASK = (1 << _LIMB_BITS) - 1
 TABLE_BYTES = TABLE_ENTRIES * _COORDS * _NLIMBS * 2  # int16
 
 # Byte budget for cached signer tables (host retention; the device
-# copy is the resident constant cache's concern). 64 MiB holds ~4.3k
+# copy lives only for the dispatch that uses it). 64 MiB holds ~4.3k
 # distinct hot signers at 15 KiB/table — an order of magnitude above
 # any validator set. Config pushes VERIFY_SIGNER_TABLE_BYTES through
 # configure().
@@ -105,7 +104,7 @@ _ENABLED_DEFAULT = os.environ.get(
 
 def signer_fingerprint(pk: bytes) -> bytes:
     """Content key of one signer: SHA-256 of the 32-byte pubkey
-    encoding, truncated like the residency/transfer-ledger keys. The
+    encoding, truncated like the transfer-ledger keys. The
     encoding (not the point) is the key on purpose — a non-canonical
     alias of a cached key must MISS and take the cold path, where the
     host canonical-A gate vetoes it."""
@@ -156,11 +155,10 @@ class _Entry:
 class SignerTableCache:
     """Process-wide LRU of per-pubkey hot-path tables, byte-bounded.
 
-    The structural sibling of ``residency.DeviceResidentCache`` — same
-    lock discipline, same sentinel-gated byte budget, same
-    content-derived keys — but holding HOST arrays keyed by signer
-    (every table has one shape/dtype, so the key is the fingerprint
-    alone), with an explicit :meth:`evict` for audit convictions."""
+    Lock-guarded, sentinel-gated byte budget, content-derived keys,
+    holding HOST arrays keyed by signer (every table has one
+    shape/dtype, so the key is the fingerprint alone), with an
+    explicit :meth:`evict` for audit convictions."""
 
     def __init__(self, max_bytes: int = DEFAULT_CACHE_BYTES,
                  enabled: bool = _ENABLED_DEFAULT):
@@ -316,8 +314,8 @@ class SignerTableCache:
         """Drop every table and tally AND restore the knob defaults —
         process-start equivalence (a test that disabled the partition
         or shrank the budget must not leak that into the next test).
-        Cumulative registry counters are untouched (residency's
-        policy)."""
+        Cumulative registry counters are untouched (the dispatch
+        layer's policy)."""
         with self._lock:
             self._entries.clear()
             self._seen_once.clear()
@@ -334,5 +332,5 @@ class SignerTableCache:
 
 # process-wide cache (one node per process — signer hotness is a
 # property of the node's traffic, shared by every verifier instance,
-# like the resident constant cache and the device-health registry)
+# like the device-health registry)
 signer_table_cache = SignerTableCache()

@@ -14,13 +14,8 @@ each resolve yields
 * ``bytes_h2d`` / ``bytes_d2h`` — payload bytes each direction;
 * ``redundant_constant_bytes`` — bytes whose CONTENT FINGERPRINT
   (SHA-256 of the uploaded bytes) was already uploaded before: the
-  smoking gun for re-shipped constants. The device-resident constant
-  cache (:mod:`stellar_tpu.parallel.residency`) now suppresses these
-  re-uploads entirely — a recurring operand is served from the
-  resident buffer and recorded here as a ``resident_hit`` (bytes the
-  engine did NOT move) instead of h2d traffic, so after warm-up this
-  counter sits at ~0 and any regrowth is a regression
-  (``tools/perf_sentinel.py`` pins it to a near-zero ceiling).
+  smoking gun for re-shipped constants (``tools/perf_sentinel.py``
+  pins it to a near-zero ceiling in bench records).
 
 Totals surface in ``dispatch_health()["transfer"]``, the Prometheus
 export (``crypto.transfer.*`` counters), and every ``bench.py`` record
@@ -43,7 +38,7 @@ from __future__ import annotations
 import hashlib
 import threading
 from collections import OrderedDict, deque
-from typing import Optional, Sequence
+from typing import Optional
 
 from stellar_tpu.utils.metrics import registry
 
@@ -65,10 +60,6 @@ DEFAULT_FP_MAX_BYTES = 1 << 20
 
 _NS = "crypto.transfer"
 
-# sentinel: "no precomputed fingerprint passed" (None is a legitimate
-# value meaning "over the size cap — count bytes-only")
-_UNSET = object()
-
 
 class ResolveLog:
     """Accumulator for ONE resolve's transfers (opaque token: the
@@ -77,8 +68,7 @@ class ResolveLog:
 
     __slots__ = ("ns", "round_trips", "bytes_h2d", "bytes_d2h",
                  "device_puts", "fetches", "redundant_constant_bytes",
-                 "redundant_uploads", "resident_hits",
-                 "resident_bytes", "finished")
+                 "redundant_uploads", "finished")
 
     def __init__(self, ns: str):
         self.ns = ns
@@ -89,8 +79,6 @@ class ResolveLog:
         self.fetches = 0
         self.redundant_constant_bytes = 0
         self.redundant_uploads = 0
-        self.resident_hits = 0
-        self.resident_bytes = 0
         self.finished = False
 
     def snapshot_locked(self) -> dict:
@@ -102,9 +90,7 @@ class ResolveLog:
                 "fetches": self.fetches,
                 "redundant_constant_bytes":
                     self.redundant_constant_bytes,
-                "redundant_uploads": self.redundant_uploads,
-                "resident_hits": self.resident_hits,
-                "resident_bytes": self.resident_bytes}
+                "redundant_uploads": self.redundant_uploads}
 
 
 class TransferLedger:
@@ -132,8 +118,6 @@ class TransferLedger:
         self._fetches = 0
         self._redundant_constant_bytes = 0
         self._redundant_uploads = 0
-        self._resident_hits = 0
-        self._resident_bytes = 0
         self._resolves_finished = 0
 
     def configure(self, resolves: Optional[int] = None,
@@ -162,7 +146,7 @@ class TransferLedger:
         return ResolveLog(ns)
 
     def record_h2d(self, tok: Optional[ResolveLog], arr,
-                   device: Optional[int] = None, fp=_UNSET) -> int:
+                   device: Optional[int] = None) -> int:
         """One host→device upload (``device_put`` or a committed
         dispatch operand). Fingerprints the CONTENT: a fingerprint
         seen before means these exact bytes were already shipped —
@@ -171,12 +155,10 @@ class TransferLedger:
         the hash runs on the dispatch hot path, so its cost must stay
         bounded, and a sampled/partial hash could convict different
         content as redundant — the skipped uploads are tallied in
-        ``unfingerprinted_uploads`` instead. ``fp`` lets the engine
-        pass the fingerprint it already computed for the resident
-        cache (one SHA-256 per upload, not two); omit it and the
-        ledger hashes for itself. Returns the byte count."""
+        ``unfingerprinted_uploads`` instead. Returns the byte count."""
         nbytes = int(arr.nbytes)
-        if fp is _UNSET and nbytes <= self._fp_max_bytes:
+        fp = None
+        if nbytes <= self._fp_max_bytes:
             # zero-copy for the engine's C-contiguous operands (axis-0
             # slices / concatenate results); tobytes() only as the
             # fallback for exotic layouts
@@ -187,8 +169,6 @@ class TransferLedger:
             except TypeError:
                 buf = arr.tobytes()
             fp = hashlib.sha256(buf).digest()[:16]
-        elif fp is _UNSET:
-            fp = None
         with self._lock:
             if fp is not None:
                 seen = self._fingerprints.pop(fp, 0)
@@ -217,33 +197,6 @@ class TransferLedger:
             registry.counter(
                 f"{_NS}.redundant_constant_bytes").inc(nbytes)
             registry.counter(f"{_NS}.redundant_uploads").inc()
-        return nbytes
-
-    def record_h2d_many(self, tok: Optional[ResolveLog],
-                        arrays: Sequence,
-                        device: Optional[int] = None) -> int:
-        """Upload of one operand tuple; returns total bytes."""
-        return sum(self.record_h2d(tok, a, device=device)
-                   for a in arrays)
-
-    def record_resident_hit(self, tok: Optional[ResolveLog], arr,
-                            device: Optional[int] = None) -> int:
-        """One operand served from the device-resident constant cache
-        (:mod:`stellar_tpu.parallel.residency`): NO bytes moved, no
-        fingerprint churn — the upload the redundancy detector used
-        to convict simply never happens. Tallied separately so the
-        bench record shows both sides of the rework: h2d collapsing
-        AND the resident hits that replaced it. Returns the byte
-        count the hit avoided."""
-        nbytes = int(arr.nbytes)
-        with self._lock:
-            self._resident_hits += 1
-            self._resident_bytes += nbytes
-            if tok is not None:
-                tok.resident_hits += 1
-                tok.resident_bytes += nbytes
-        registry.counter(f"{_NS}.resident_hits").inc()
-        registry.counter(f"{_NS}.resident_bytes").inc(nbytes)
         return nbytes
 
     def record_d2h(self, tok: Optional[ResolveLog], arr,
@@ -292,8 +245,9 @@ class TransferLedger:
                 "redundant_constant_bytes":
                     self._redundant_constant_bytes,
                 "redundant_uploads": self._redundant_uploads,
-                "resident_hits": self._resident_hits,
-                "resident_bytes": self._resident_bytes,
+                # benchmark/harness.py reads this key from
+                # dispatch_health()["transfer"]; nothing else sets it
+                "resident_hits": 0,
                 "resolves_recorded": self._resolves_finished,
                 "fingerprints_tracked": len(self._fingerprints),
                 "unfingerprinted_uploads":
@@ -325,8 +279,6 @@ class TransferLedger:
             self._fetches = 0
             self._redundant_constant_bytes = 0
             self._redundant_uploads = 0
-            self._resident_hits = 0
-            self._resident_bytes = 0
             self._resolves_finished = 0
 
 

@@ -97,12 +97,7 @@ def main():
             "device_served": {str(k): n
                               for k, n in sorted(v.device_served.items())},
             "kernel_shapes": sorted(v._kernels),
-            # ISSUE 12: donating wrappers would be SECOND executables
-            # per shape — on jax-CPU (donation auto-off) this must
-            # stay empty, or the compile-reuse budget silently doubles
-            "donate_kernel_shapes": sorted(v._kernels_donate),
             "coalesced_dispatches": v.coalesced_dispatches,
-            "resident_hits": v.resident_hits,
             "quarantined": health.quarantined(N_DEV),
             "host_only": bv.host_only_mode(),
             "audit_mismatches": v.audit_mismatches,
@@ -180,10 +175,6 @@ def main():
     out["dispatch_health"] = {
         k: bv.dispatch_health()[k]
         for k in ("host_only", "audit", "device_health")}
-    # ISSUE 12: the resident constant cache's process totals — the
-    # chaos run re-dispatches the same 16 items every phase, so the
-    # cache must show real hits (uploads suppressed) by the end
-    out["resident"] = bv.dispatch_health()["resident"]
     out["breaker_history"] = health.history()
 
     # ---- phases 6-7: hot-signer table cache vs audit conviction

@@ -744,26 +744,13 @@ def test_lint_scopes_cover_transfer_ledger_and_sentinel():
         assert mod not in nondet.ALLOWLIST._entries, mod
 
 
-def test_lint_scopes_cover_residency_cache():
-    """ISSUE 12: the device-resident constant cache's LRU mutates
-    from every dispatching thread through the engine's placement path
-    (lock lint), and it decides WHICH operand uploads are skipped —
-    keys must stay content-derived and eviction clock/RNG-free
-    (nondet lint). No allowlist entry: clock/RNG-free by design, like
-    the transfer ledger whose redundancy detector it answers."""
-    res = "stellar_tpu/parallel/residency.py"
-    assert res in set(locks.SCOPE)
-    assert res in set(nondet.HOST_ORACLE_FILES)
-    assert res not in nondet.ALLOWLIST._entries
-
-
 def test_lint_scopes_cover_signer_tables():
     """ISSUE 16: the per-pubkey table cache's LRU mutates from every
     partitioning submit thread (lock lint), and it decides which rows
     ride the hot kernel — fingerprints must stay content-derived and
     eviction clock/RNG-free (nondet lint), or replicas diverge on
     which kernel variant served a row. No allowlist entry: clock/
-    RNG-free by design, like residency.py whose shape it follows."""
+    RNG-free by design, like the transfer ledger."""
     st = "stellar_tpu/parallel/signer_tables.py"
     assert st in set(locks.SCOPE)
     assert st in set(nondet.HOST_ORACLE_FILES)
@@ -1199,7 +1186,6 @@ def test_scope_sets_pinned():
         "stellar_tpu/parallel/batch_engine.py",
         "stellar_tpu/parallel/device_health.py",
         "stellar_tpu/parallel/hostbuf.py",
-        "stellar_tpu/parallel/residency.py",
         "stellar_tpu/parallel/signer_tables.py",
         "stellar_tpu/soroban/native_wasm.py",
         "stellar_tpu/utils/faults.py",

@@ -128,26 +128,13 @@ def test_coalesced_dispatch_reuses_subchunk_kernels(domain_run):
     that follows it compiled any shape beyond the single sub-chunk
     executable — the coalesced path feeds the SAME per-device
     executables the legacy path uses, so degradation under it still
-    pays zero fresh XLA compiles. Donating wrappers (a second
-    executable per shape) must not exist on jax-CPU, where donation
-    is auto-off."""
+    pays zero fresh XLA compiles."""
     base = domain_run["phases"]["baseline"]
     degraded = domain_run["phases"]["degraded"]
     assert base["coalesced_dispatches"] > 0
     # degradation leaves the coalesced path (assignment != identity)
-    # without minting new dispatch shapes of EITHER kind
+    # without minting a new dispatch shape
     assert degraded["kernel_shapes"] == base["kernel_shapes"] == [2]
-    assert degraded["donate_kernel_shapes"] == \
-        base["donate_kernel_shapes"] == []
-    # and re-resolving identical content was served from the resident
-    # constant cache: the cumulative hit counter is nonzero by the
-    # degraded phase (the fail_device_1 re-resolve already hit), and
-    # the process-wide cache shows live entries — zero re-uploaded
-    # constant bytes is the acceptance number the transfer selfcheck
-    # pins process-wide
-    assert degraded["resident_hits"] > 0
-    assert domain_run["resident"]["entries"] > 0
-    assert domain_run["resident"]["hits"] > 0
 
 
 @lifecycle
@@ -200,7 +187,6 @@ def test_hot_signer_table_serves_on_chaos_mesh(domain_run):
     assert st["hits"] > 0
     assert st["audit_evictions"] == 0
     assert ph["kernel_shapes"] == [2]
-    assert ph["donate_kernel_shapes"] == []
 
 
 @lifecycle

@@ -148,12 +148,11 @@ class _XferWorkload(batch_engine.Workload):
 
 def test_engine_device_path_records_and_reconciles():
     """A dispatched resolve records h2d at the upload, d2h + a round
-    trip at the fetch; a SECOND resolve of identical content is served
-    from the device-resident constant cache (ISSUE 12) — ZERO new h2d
-    bytes, zero redundant bytes, a resident hit per operand — and the
+    trip at the fetch; a SECOND resolve of identical content uploads
+    again (the engine keeps no device buffer past its call) and the
+    redundancy detector convicts exactly that re-upload — and the
     ledger's deltas still reconcile EXACTLY with the engine's own
-    shape-derived accounting (both sides skip the upload that never
-    happened)."""
+    shape-derived accounting."""
     eng = batch_engine.BatchEngine(_XferWorkload(), bucket_sizes=(4,))
     items = [10, 20, 30, 40]
     before = transfer_ledger.totals()
@@ -165,53 +164,46 @@ def test_engine_device_path_records_and_reconciles():
     assert mid["round_trips"] - before["round_trips"] == 1
     assert mid["redundant_constant_bytes"] == \
         before["redundant_constant_bytes"]
-    out = eng.compute_batch(items)  # identical content: resident hit
+    out = eng.compute_batch(items)  # identical content: a second upload
     assert list(out) == items
     after = transfer_ledger.totals()
-    assert after["bytes_h2d"] == mid["bytes_h2d"]  # nothing re-shipped
-    assert after["redundant_constant_bytes"] == \
-        mid["redundant_constant_bytes"] == 0
-    assert after["resident_hits"] - before["resident_hits"] == 1
-    assert after["resident_bytes"] - before["resident_bytes"] == 8
-    assert eng.resident_hits == 1
+    assert after["bytes_h2d"] - mid["bytes_h2d"] == 8
+    assert after["redundant_constant_bytes"] - \
+        mid["redundant_constant_bytes"] == 8
+    assert after["device_puts"] - before["device_puts"] == 2
     # reconciliation: ledger deltas == engine's independent tally
-    # (the resident hit moved no bytes on EITHER side)
     assert after["bytes_h2d"] - before["bytes_h2d"] == \
-        eng.shipped_bytes == 8
+        eng.shipped_bytes == 16
     assert after["bytes_d2h"] - before["bytes_d2h"] == \
         eng.fetched_bytes == 8
     # per-resolve records landed in the ring; the second resolve's
-    # record carries the resident hit instead of redundant bytes
+    # record carries the redundant re-upload
     last = transfer_ledger.recent(2)
     assert [r["round_trips"] for r in last] == [1, 1]
-    assert last[-1]["redundant_constant_bytes"] == 0
-    assert last[-1]["resident_hits"] == 1
-    assert last[-1]["bytes_h2d"] == 0
+    assert [r["bytes_h2d"] for r in last] == [8, 8]
+    assert [r["redundant_constant_bytes"] for r in last] == [0, 8]
+    assert "resident_hits" not in last[-1]
 
 
-def test_redundancy_detector_still_convicts_without_residency():
-    """The instrument outlives the fix: with the resident cache
-    disabled, re-dispatching identical content re-ships it and the
-    ledger's redundancy detector counts every byte — the exact
-    pre-ISSUE-12 indictment shape, kept testable so the detector
-    can't silently rot while the cache hides re-uploads."""
-    from stellar_tpu.parallel.residency import resident_cache
-    eng = batch_engine.BatchEngine(_XferWorkload(), bucket_sizes=(4,))
+def test_redundancy_detector_convicts_repeats_across_engines():
+    """The detector's fingerprints are process-wide: the same bytes
+    shipped by a second engine are convicted as a re-upload, while
+    different content is not."""
     items = [50, 60, 70, 80]
     before = transfer_ledger.totals()
-    resident_cache.configure(enabled=False)
-    try:
-        assert list(eng.compute_batch(items)) == items
-        assert list(eng.compute_batch(items)) == items
-    finally:
-        resident_cache.configure(enabled=True)
+    eng = batch_engine.BatchEngine(_XferWorkload(), bucket_sizes=(4,))
+    assert list(eng.compute_batch(items)) == items
+    other = batch_engine.BatchEngine(_XferWorkload(), bucket_sizes=(4,))
+    assert list(other.compute_batch(items)) == items
+    assert list(other.compute_batch([51, 61, 71, 81])) == \
+        [51, 61, 71, 81]
     after = transfer_ledger.totals()
-    assert after["bytes_h2d"] - before["bytes_h2d"] == 16
+    assert after["bytes_h2d"] - before["bytes_h2d"] == 24
     assert after["redundant_constant_bytes"] - \
         before["redundant_constant_bytes"] == 8
-    assert after["resident_hits"] == before["resident_hits"]
-    # both uploads really shipped: engine tally matches the ledger
-    assert eng.shipped_bytes == 16
+    assert after["redundant_uploads"] - before["redundant_uploads"] == 1
+    # every upload really shipped: engine tallies match the ledger
+    assert eng.shipped_bytes + other.shipped_bytes == 24
 
 
 def test_host_only_resolve_moves_zero_bytes():

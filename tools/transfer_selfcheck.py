@@ -1,37 +1,32 @@
 #!/usr/bin/env python
-"""TRANSFER_LEDGER_OK self-check (run by ``tools/tier1.sh``; ISSUE 8,
-reworked for the ISSUE 12 dispatch-floor levers).
+"""TRANSFER_LEDGER_OK self-check (run by ``tools/tier1.sh``).
 
-Proves the transfer ledger AND the device-resident constant cache
-end-to-end on a forced-4-device CHAOS resolve — CPU backend, the
-SHA-256 engine workload (its scan-based kernel compiles in seconds,
-against the shared persistent cache), with ``flaky-device:0`` armed so
-the recorded window includes real fault-domain traffic (failed
-dispatches, host fallback) and not just the happy path. Three phases:
+Proves the transfer ledger end-to-end on a forced-4-device CHAOS
+resolve — CPU backend, the SHA-256 engine workload (its scan-based
+kernel compiles in seconds, against the shared persistent cache), with
+``flaky-device:0`` armed so the recorded window includes real
+fault-domain traffic (failed dispatches, host fallback) and not just
+the happy path. Three phases:
 
-1. **detector** (resident cache DISABLED): two resolves of the SAME
-   batch must yield nonzero ``round_trips`` AND nonzero
-   ``redundant_constant_bytes`` — the redundancy instrument still
-   convicts re-uploads, so it can't silently rot while the cache
-   hides them;
-2. **resident** (cache re-enabled, the production default; the chaos
-   window): re-resolving the same batch must record ``resident_hits``
-   > 0 and ``redundant_constant_bytes`` == 0 — constants upload once
-   per placement per process, the ISSUE 12 acceptance number (and the
-   near-zero ceiling ``tools/perf_sentinel.py`` pins);
+1. **detector**: two resolves of the SAME batch must yield nonzero
+   ``round_trips`` AND nonzero ``redundant_constant_bytes`` — the
+   redundancy instrument convicts re-uploads;
+2. **steady state** (the chaos window): resolves of FRESH batches, as
+   every benchmark cell sends. Their message words are new bytes, so
+   at most a minority of the shipped bytes may be convicted as
+   re-uploads (the small per-row ``active`` masks of same-length
+   messages repeat);
 3. the ledger's byte totals must RECONCILE (>= MIN_RECONCILE both
    directions) against the engine's own independent shape-derived
-   accounting of what it shipped and fetched — resident hits are
-   skipped by BOTH tallies, so a placement path that forgets its
-   ledger hook still shows up as a byte gap; the
-   ``crypto.transfer.*`` counters (including ``resident_hits``) must
-   ride the Prometheus exposition; and digests stay bit-identical to
-   hashlib through the flap (no lever may ever change a result).
+   accounting of what it shipped and fetched, so a placement path
+   that forgets its ledger hook shows up as a byte gap; the
+   ``crypto.transfer.*`` counters must ride the Prometheus
+   exposition; and digests stay bit-identical to hashlib through the
+   flap.
 
-The TOP-LEVEL fields are the steady-state (resident) window — the
-numbers bench.py embeds and the sentinel gates; the ``detector``
-block carries the cache-off conviction evidence. Prints one JSON
-line; exit 0 = every check passed.
+The TOP-LEVEL fields are the steady-state window; the ``detector``
+block carries the conviction evidence. Prints one JSON line; exit 0 =
+every check passed.
 """
 
 import argparse
@@ -55,8 +50,9 @@ def _env_setup() -> None:
     enable_compile_cache()
 
 
-def _corpus(n: int):
-    return [bytes(((7 * j + k) % 256) for k in range(40 + 13 * j))
+def _corpus(n: int, salt: int = 0):
+    return [bytes(((7 * j + k + 31 * salt) % 256)
+                  for k in range(40 + 13 * j))
             for j in range(n)]
 
 
@@ -72,7 +68,6 @@ def run() -> dict:
     from stellar_tpu.crypto import batch_hasher as bh
     from stellar_tpu.crypto import batch_verifier as bv
     from stellar_tpu.parallel.mesh import batch_mesh
-    from stellar_tpu.parallel.residency import resident_cache
     from stellar_tpu.utils import faults
     from stellar_tpu.utils.metrics import registry
     from stellar_tpu.utils.transfer_ledger import transfer_ledger
@@ -94,21 +89,18 @@ def run() -> dict:
     msgs = _corpus(BUCKET)
     want = [hashlib.sha256(m).digest() for m in msgs]
 
-    # warm compile (clean, resident cache ON — the first upload of
-    # this content seeds the cache, as warm-up does in production)
-    mismatches = sum(1 for g, w in zip(h.hash_batch(msgs), want)
-                     if g != w)
+    def mismatched(batch, expect):
+        return sum(1 for g, w in zip(h.hash_batch(batch), expect)
+                   if g != w)
 
-    # ---- phase 1: detector, cache OFF (the pre-rework indictment
-    # shape — the instrument must still convict re-uploads) ----
-    resident_cache.configure(enabled=False)
+    # warm compile (clean)
+    mismatches = mismatched(msgs, want)
+
+    # ---- phase 1: detector — the same batch again must be convicted
+    # as a re-upload ----
     det_before = transfer_ledger.totals()
-    try:
-        for _ in range(2):
-            mismatches += sum(
-                1 for g, w in zip(h.hash_batch(msgs), want) if g != w)
-    finally:
-        resident_cache.configure(enabled=True)
+    for _ in range(2):
+        mismatches += mismatched(msgs, want)
     det_after = transfer_ledger.totals()
     detector = {k: det_after[k] - det_before[k]
                 for k in ("round_trips", "bytes_h2d",
@@ -118,18 +110,14 @@ def run() -> dict:
         detector["redundant_constant_bytes"]
         / max(1, detector["bytes_h2d"]), 4)
 
-    # ---- phase 2: resident steady state, cache ON (the production
-    # default) — the CHAOS window bench.py embeds ----
-    # first resolve re-seeds the cache (the detector phase uploaded
-    # with retention off), then the measured window must be all hits
-    mismatches += sum(1 for g, w in zip(h.hash_batch(msgs), want)
-                      if g != w)
+    # ---- phase 2: steady state — fresh batches under the flap ----
+    fresh = [_corpus(BUCKET, salt) for salt in (1, 2)]
     before = transfer_ledger.totals()
     faults.set_fault(faults.DISPATCH, "flaky-device", 0)
     try:
-        for _ in range(2):
-            mismatches += sum(
-                1 for g, w in zip(h.hash_batch(msgs), want) if g != w)
+        for batch in fresh:
+            mismatches += mismatched(
+                batch, [hashlib.sha256(m).digest() for m in batch])
     finally:
         fault_counters = faults.counters()
         faults.clear()
@@ -141,11 +129,10 @@ def run() -> dict:
              for k in ("round_trips", "bytes_h2d", "bytes_d2h",
                        "device_puts", "fetches",
                        "redundant_constant_bytes",
-                       "redundant_uploads", "resident_hits",
-                       "resident_bytes")}
+                       "redundant_uploads")}
     # reconciliation: ledger totals vs the engine's OWN shape-derived
-    # accounting, over the whole run (warm + detector + resident
-    # phases on both sides; resident hits move zero bytes on either)
+    # accounting, over the whole run (warm + detector + steady phases
+    # on both sides)
     rec_h2d = _ratio(after["bytes_h2d"], shipped1)
     rec_d2h = _ratio(after["bytes_d2h"], fetched1)
     reconciliation = min(x for x in (rec_h2d, rec_d2h)
@@ -158,24 +145,18 @@ def run() -> dict:
         problems.append(f"{mismatches} digests mismatched hashlib "
                         "under the flap")
     if detector["redundant_constant_bytes"] == 0:
-        problems.append("cache-off re-ship recorded zero redundant "
-                        "constant bytes — the redundancy detector "
-                        "has rotted")
+        problems.append("re-shipping the same batch recorded zero "
+                        "redundant constant bytes — the redundancy "
+                        "detector has rotted")
     if detector["round_trips"] == 0:
         problems.append("detector window recorded zero round trips")
     if delta["round_trips"] == 0:
         problems.append("chaos window recorded zero round trips")
-    if delta["redundant_constant_bytes"] != 0:
+    if 2 * delta["redundant_constant_bytes"] >= delta["bytes_h2d"]:
         problems.append(
-            "resident window re-shipped "
-            f"{delta['redundant_constant_bytes']} redundant constant "
-            "bytes — the device-resident cache is not absorbing "
-            "re-uploads (constants must upload once per placement "
-            "per process)")
-    if delta["resident_hits"] == 0:
-        problems.append("resident window recorded zero resident hits "
-                        "— re-dispatched content did not ride the "
-                        "cache")
+            "fresh batches convicted as re-uploads: "
+            f"{delta['redundant_constant_bytes']} of "
+            f"{delta['bytes_h2d']} h2d bytes")
     if delta["bytes_d2h"] == 0:
         problems.append(f"d2h byte accounting empty: {delta}")
     if reconciliation is None or reconciliation < MIN_RECONCILE:
@@ -188,7 +169,7 @@ def run() -> dict:
         problems.append("flaky-device:0 never fired — not a chaos "
                         "window")
     if "crypto_transfer_bytes_h2d" not in prom or \
-            "crypto_transfer_resident_hits" not in prom:
+            "crypto_transfer_redundant_constant_bytes" not in prom:
         problems.append("transfer counters missing from the "
                         "Prometheus exposition")
     per_resolve = transfer_ledger.recent(2)
@@ -199,7 +180,7 @@ def run() -> dict:
         "ok": not problems,
         "devices": len(devs),
         "bucket": BUCKET,
-        # steady-state (resident) window — the gated trajectory
+        # steady-state window — the gated trajectory
         "round_trips": delta["round_trips"],
         "bytes_h2d": delta["bytes_h2d"],
         "bytes_d2h": delta["bytes_d2h"],
@@ -207,21 +188,16 @@ def run() -> dict:
         "fetches": delta["fetches"],
         "redundant_constant_bytes": delta["redundant_constant_bytes"],
         "redundant_uploads": delta["redundant_uploads"],
-        "resident_hits": delta["resident_hits"],
-        "resident_bytes": delta["resident_bytes"],
         "reconciliation": round(reconciliation, 4)
         if reconciliation is not None else None,
-        # scale-free redundancy fraction of the steady-state window:
-        # ~0 with the resident cache live (was 1.0 pre-rework); the
-        # sentinel guards regrowth
+        # scale-free redundancy fraction of the steady-state window
         "redundancy_frac": round(
             delta["redundant_constant_bytes"] /
             max(1, delta["bytes_h2d"]), 4)
         if delta["bytes_h2d"] else 0.0,
         "engine_shipped_bytes": shipped1,
         "engine_fetched_bytes": fetched1,
-        "resident": resident_cache.snapshot(),
-        # cache-off conviction evidence: the detector still works
+        # conviction evidence: the detector still works
         "detector": detector,
         "last_resolves": per_resolve,
         "workload": "sha256",
